@@ -98,7 +98,7 @@ def bench_objective_backends(rows):
                  f"speedup_vs_exact={us_exact / us_fast:.1f}x"))
     rows.append(("mpad_objective_exact_N2048", us_exact, "paper_faithful"))
     rows.append(("mpad_objective_kernel_N2048", us_kern,
-                 "pallas_interpret_cpu"))
+                 f"pallas_on_{jax.default_backend()}"))
 
 
 def bench_kernels(rows):
@@ -107,7 +107,8 @@ def bench_kernels(rows):
     x = jax.random.normal(jax.random.key(1), (4096, 64))
     us_k = _timeit(knn_topk_pallas, q, x, 10, reps=2)
     us_r = _timeit(knn_ref, q, x, 10)
-    rows.append(("knn_topk_pallas_interp_4096", us_k, "interpret_mode"))
+    rows.append(("knn_topk_pallas_4096", us_k,
+                 f"pallas_on_{jax.default_backend()}"))
     rows.append(("knn_ref_jnp_4096", us_r, "oracle"))
 
 
@@ -428,7 +429,6 @@ def bench_serve_fused(rows, json_doc=None, fast=False):
             # re-run deep_trace samples in production, at bench precision
             kwd = dict(nprobe=eng.config.nprobe, rerank=eng.config.rerank,
                        backend=eng.config.pq_backend,
-                       interpret=eng.config.pq_interpret,
                        lut_dtype="f32", scan_cap=0, prefilter=0)
             breakdown = []
             for b in (1, 64, nq):
@@ -865,6 +865,8 @@ def main(argv=None) -> None:
     ap.add_argument("--fast", action="store_true",
                     help="CI subset: kernels + the fused serving bench only")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = []
     json_doc = {"schema": "qpad.bench_serve.v1",
                 "created_unix": round(time.time())} if args.json else None

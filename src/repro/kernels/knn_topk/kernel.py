@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import resolve_interpret
+
 _INF = float("inf")
 _BIGI = 2**31 - 1
 
@@ -71,7 +73,7 @@ def _knn_kernel(n_total, k, q_ref, x_ref, best_d_ref, best_i_ref):
                    static_argnames=("k", "block_q", "block_n", "interpret"))
 def knn_topk_pallas(q: jax.Array, x: jax.Array, k: int,
                     block_q: int = 128, block_n: int = 512,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Blocked exact k-NN. Returns (d2 (Q,k) ascending, idx (Q,k))."""
     nq, d = q.shape
     n = x.shape[0]
@@ -95,7 +97,7 @@ def knn_topk_pallas(q: jax.Array, x: jax.Array, k: int,
             jax.ShapeDtypeStruct((qp.shape[0], k), jnp.float32),
             jax.ShapeDtypeStruct((qp.shape[0], k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp.astype(jnp.float32), xp.astype(jnp.float32))
     bd, bi = bd[:nq], bi[:nq]
     order = jnp.argsort(bd, axis=1)                      # ascending final sort

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import resolve_interpret
+
 DEFAULT_BLOCK = 256
 
 
@@ -64,7 +66,7 @@ def _stats_kernel(n_total, pi_ref, pj_ref, tau_ref, c_ref, cnt_ref, s_ref):
 def pairwise_stats_pallas(p: jax.Array, tau: jax.Array,
                           block_i: int = DEFAULT_BLOCK,
                           block_j: int = DEFAULT_BLOCK,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Tiled threshold statistics. Returns (count i32, sum f32, coeff (N,))."""
     n = p.shape[0]
     pad = (-n) % max(block_i, block_j)
@@ -91,7 +93,7 @@ def pairwise_stats_pallas(p: jax.Array, tau: jax.Array,
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(p2, p2, tau2)
     coeff = c[:n, 0]
     return cnt[0, 0] // 2, s[0, 0] * 0.5, coeff

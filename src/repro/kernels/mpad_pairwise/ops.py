@@ -21,7 +21,7 @@ from .kernel import pairwise_stats_pallas
 
 @functools.partial(jax.jit, static_argnames=("b", "interpret", "block"))
 def mu_kernel_value_and_grad(w: jax.Array, x: jax.Array, *, b: float,
-                             interpret: bool = True, block: int = 256):
+                             interpret: bool | None = None, block: int = 256):
     """Value and tangent gradient of mu_b at unit ``w`` via the Pallas kernel."""
     k_pairs = num_selected_pairs(x.shape[0], b)
     wn = w / jnp.linalg.norm(w)
@@ -39,7 +39,7 @@ def mu_kernel_value_and_grad(w: jax.Array, x: jax.Array, *, b: float,
 
 @functools.partial(jax.jit, static_argnames=("b", "alpha", "interpret", "block"))
 def phi_kernel_value_and_grad(w, x, prev, prev_mask, *, b: float, alpha: float,
-                              interpret: bool = True, block: int = 256):
+                              interpret: bool | None = None, block: int = 256):
     """Trainer backend contract (see repro.core.mpad._get_backend)."""
     mu, g_mu = mu_kernel_value_and_grad(w, x, b=b, interpret=interpret,
                                         block=block)

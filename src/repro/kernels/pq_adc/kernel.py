@@ -31,8 +31,15 @@ Two entry points share the merge:
 * ``pq_adc_gather_topk_pallas``— per-query (C, M) candidate codes plus a
   per-candidate additive ``base`` (the IVF-PQ residual decomposition). The
   lookup here is per-query, so the one-hot contraction runs on the VPU
-  ((BQ, BN, K) masked sum — int32 select/add for int8) — block defaults are
-  smaller to bound VMEM.
+  ((BQ, BN, K) masked sum — int32 select/add for int8). Tables and codes
+  enter subspace-leading, (M, Q, K) and (M, Q, C), and a ``fori_loop``
+  walks M on that untiled axis, so only one subspace's (BQ, BN, K)
+  temporaries are live: the default blocks fit the 16 MiB scoped VMEM of
+  a v5e at every width up to M=16, K=256 (an unrolled loop did not).
+
+Both entry points take ``interpret=None``: the platform decides
+(``repro.kernels.platform.resolve_interpret`` — compiled on a TPU,
+interpreted elsewhere).
 
 Layout notes: codes enter the shared kernel transposed (M, N) so a subspace
 row slice is a native (1, BN) lane vector; VMEM at defaults
@@ -47,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import resolve_interpret
 from .lut import quantize_lut
 
 _INF = float("inf")
@@ -119,7 +127,7 @@ def _adc_kernel(n_total, k, lut_dtype, t_ref, *refs):
                                              "interpret", "lut_dtype"))
 def pq_adc_topk_pallas(tables: jax.Array, codes: jax.Array, k: int,
                        block_q: int = 128, block_n: int = 512,
-                       interpret: bool = True, lut_dtype: str = "f32",
+                       interpret: bool | None = None, lut_dtype: str = "f32",
                        scale=None):
     """Fused ADC scan over a shared code matrix.
 
@@ -159,7 +167,7 @@ def pq_adc_topk_pallas(tables: jax.Array, codes: jax.Array, k: int,
             jax.ShapeDtypeStruct((tp.shape[0], k), jnp.float32),
             jax.ShapeDtypeStruct((tp.shape[0], k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*inputs)
     bd, bi = bd[:nq], bi[:nq]
     order = jnp.argsort(bd, axis=1)                          # ascending sort
@@ -173,26 +181,24 @@ def _adc_gather_kernel(c_total, k, lut_dtype, t_ref, *refs):
     else:
         (c_ref, base_ref, best_d_ref, best_i_ref), s_ref = refs, None
     j = pl.program_id(1)
-    tables = t_ref[...]                                      # (BQ, M, K)
-    bq, m, kc = tables.shape
-    bn = c_ref.shape[1]
-    cent = jax.lax.broadcasted_iota(jnp.int32, (bq, bn, kc), 2)
+    m, bq, kc = t_ref.shape                                  # (M, BQ, K)
+    bn = c_ref.shape[2]                                      # (M, BQ, BN)
+    acc_dt = jnp.int32 if lut_dtype == "int8" else jnp.float32
+
+    def subspace(sub, acc):
+        # one subspace per loop step: the (BQ, BN, K) iota and select live
+        # for one step only, so VMEM does not grow with M. ``sub`` indexes
+        # the untiled leading axis, which Mosaic slices at any offset.
+        codes = c_ref[sub].astype(jnp.int32)                 # (BQ, BN)
+        t = t_ref[sub].astype(acc_dt)                        # (BQ, K)
+        cent = jax.lax.broadcasted_iota(jnp.int32, (bq, bn, kc), 2)
+        hit = codes[:, :, None] == cent
+        return acc + jnp.sum(jnp.where(hit, t[:, None, :], 0), axis=2)
+
+    acc = jax.lax.fori_loop(0, m, subspace, jnp.zeros((bq, bn), acc_dt))
+    lut = acc.astype(jnp.float32)
     if lut_dtype == "int8":
-        ti = tables.astype(jnp.int32)
-        acc = jnp.zeros((bq, bn), jnp.int32)
-        for sub in range(m):                                 # M static: unroll
-            # uint8 codes widen in-register; gathered bytes stay narrow
-            hit = c_ref[:, :, sub].astype(jnp.int32)[:, :, None] == cent
-            acc = acc + jnp.sum(
-                jnp.where(hit, ti[:, sub, :][:, None, :], 0), axis=2)
-        lut = acc.astype(jnp.float32) * s_ref[...]           # (BQ,BN)*(BQ,1)
-    else:
-        tf = tables.astype(jnp.float32)
-        lut = jnp.zeros((bq, bn), jnp.float32)
-        for sub in range(m):                                 # M static: unroll
-            onehot = (c_ref[:, :, sub].astype(jnp.int32)[:, :, None] == cent
-                      ).astype(jnp.float32)
-            lut = lut + jnp.sum(tf[:, sub, :][:, None, :] * onehot, axis=2)
+        lut = lut * s_ref[...]                               # (BQ,BN)*(BQ,1)
     d2 = base_ref[...].astype(jnp.float32) + lut
     gj = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bq, bn), 1)
     work = jnp.where(gj < c_total, d2, _INF)
@@ -212,8 +218,8 @@ def _adc_gather_kernel(c_total, k, lut_dtype, t_ref, *refs):
 def pq_adc_gather_topk_pallas(tables: jax.Array, codes: jax.Array,
                               base: jax.Array, k: int,
                               block_q: int = 8, block_n: int = 256,
-                              interpret: bool = True, lut_dtype: str = "f32",
-                              scale=None):
+                              interpret: bool | None = None,
+                              lut_dtype: str = "f32", scale=None):
     """Fused ADC scan over per-query gathered candidate codes.
 
     tables (Q, M, K) f32 (quantized internally per ``lut_dtype``; ``scale``
@@ -233,10 +239,13 @@ def pq_adc_gather_topk_pallas(tables: jax.Array, codes: jax.Array,
     cp = jnp.pad(codes, ((0, pad_q), (0, pad_c), (0, 0)))
     bp = jnp.pad(base, ((0, pad_q), (0, pad_c)), constant_values=_INF)
     grid = (tp.shape[0] // block_q, cp.shape[1] // block_n)
-    inputs = [tp, cp, bp.astype(jnp.float32)]  # codes at stored width (uint8)
+    # subspace-leading layouts: the kernel loops over M on the untiled
+    # axis, and the candidate axis of the codes is lane-dense
+    inputs = [tp.transpose(1, 0, 2), cp.transpose(2, 0, 1),
+              bp.astype(jnp.float32)]           # codes at stored width (uint8)
     in_specs = [
-        pl.BlockSpec((block_q, m, kc), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((block_q, block_n, m), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((m, block_q, kc), lambda i, j: (0, i, 0)),
+        pl.BlockSpec((m, block_q, block_n), lambda i, j: (0, i, j)),
         pl.BlockSpec((block_q, block_n), lambda i, j: (i, j)),
     ]
     if lut_dtype == "int8":
@@ -255,7 +264,7 @@ def pq_adc_gather_topk_pallas(tables: jax.Array, codes: jax.Array,
             jax.ShapeDtypeStruct((tp.shape[0], k), jnp.float32),
             jax.ShapeDtypeStruct((tp.shape[0], k), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*inputs)
     bd, bi = bd[:nq], bi[:nq]
     order = jnp.argsort(bd, axis=1)

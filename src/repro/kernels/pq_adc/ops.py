@@ -13,7 +13,7 @@ from .kernel import pq_adc_gather_topk_pallas, pq_adc_topk_pallas
                                              "interpret", "lut_dtype"))
 def pq_adc_topk(tables: jax.Array, codes: jax.Array, k: int, *,
                 block_q: int = 128, block_n: int = 512,
-                interpret: bool = True, lut_dtype: str = "f32"):
+                interpret: bool | None = None, lut_dtype: str = "f32"):
     """Top-k ADC over shared codes: (dists (Q,k), idx (Q,k)), sqrt'd."""
     with jax.named_scope("pq_adc.topk"):
         d2, idx = pq_adc_topk_pallas(tables, codes, k, block_q=block_q,
@@ -28,7 +28,7 @@ def pq_adc_topk(tables: jax.Array, codes: jax.Array, k: int, *,
 def pq_adc_topk_global(tables: jax.Array, codes: jax.Array, k: int, *,
                        row_offset: jax.Array, n_valid: jax.Array,
                        slack: int = 0, block_q: int = 128,
-                       block_n: int = 512, interpret: bool = True,
+                       block_n: int = 512, interpret: bool | None = None,
                        lut_dtype: str = "f32"):
     """Shard-local fused ADC scan returning GLOBAL row ids (sharded serving).
 
@@ -69,7 +69,7 @@ def pq_adc_topk_global(tables: jax.Array, codes: jax.Array, k: int, *,
                                              "interpret", "lut_dtype"))
 def pq_adc_gather_topk(tables: jax.Array, codes: jax.Array, base: jax.Array,
                        k: int, *, block_q: int = 8, block_n: int = 256,
-                       interpret: bool = True, lut_dtype: str = "f32"):
+                       interpret: bool | None = None, lut_dtype: str = "f32"):
     """Top-k ADC over per-query candidates: (dists (Q,k), slot idx (Q,k))."""
     with jax.named_scope("pq_adc.gather_topk"):
         d2, idx = pq_adc_gather_topk_pallas(tables, codes, base, k,

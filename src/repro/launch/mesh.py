@@ -11,6 +11,7 @@ import; real deployments get the same shapes from the TPU topology.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_serving_mesh"]
 
@@ -19,10 +20,11 @@ def make_serving_mesh(shards: int | None = None, axis: str = "data"):
     """1-D data-parallel mesh for sharded serving: the first ``shards``
     devices (default: all) under a single ``axis`` name.
 
-    CPU dry-runs / CI simulate the fleet with
+    Real deployments get the devices from the accelerator (a v5e host
+    has four chips). CPU dry-runs / CI simulate the fleet with
+    ``JAX_PLATFORMS=cpu`` and
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before the
-    first jax device query; real deployments get the shape from the
-    accelerator topology.
+    first jax device query.
     """
     devices = jax.devices()
     if shards is None:
@@ -30,11 +32,14 @@ def make_serving_mesh(shards: int | None = None, axis: str = "data"):
     if shards < 1:
         raise ValueError("shards must be >= 1")
     if len(devices) < shards:
+        platform = devices[0].platform
+        hint = (f" — to simulate them, set JAX_PLATFORMS=cpu and "
+                f"XLA_FLAGS=--xla_force_host_platform_device_count={shards} "
+                "before the first jax device use" if platform == "cpu"
+                else "")
         raise RuntimeError(
-            f"need {shards} devices for a serving mesh, have "
-            f"{len(devices)} — set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={shards} "
-            "before the first jax device use")
+            f"need {shards} devices for a serving mesh, this host has "
+            f"{len(devices)} {platform} device(s){hint}")
     return jax.make_mesh((shards,), (axis,), devices=devices[:shards])
 
 
@@ -50,9 +55,5 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(jax.devices())} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax (see launch/dryrun.py)")
-    try:                              # AxisType landed after jax 0.4.x;
-        from jax.sharding import AxisType   # Auto matches its old default
-        kw = {"axis_types": (AxisType.Auto,) * len(axes)}
-    except ImportError:
-        kw = {}
-    return jax.make_mesh(shape, axes, devices=devices, **kw)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -31,9 +31,14 @@ batches against the exact scan to estimate live recall — any of these
 turns on the ``latency.*`` histograms in the scrape.
 
 Sharded serving: ``--shards N`` partitions the engine state over an N-way
-data mesh (``--mesh host`` simulates the N devices on CPU — useful for
-dry-runs; it must run before jax touches its backend, which this launcher
-guarantees by setting XLA_FLAGS before the first jax call).
+data mesh (``--mesh host`` simulates the N devices on the CPU backend —
+useful for dry-runs; it sets ``JAX_PLATFORMS=cpu`` and ``XLA_FLAGS`` before
+the first jax call, so it never asks a TPU host for devices it lacks).
+
+The ADC kernel (``@kernel`` in a spec) runs compiled on a TPU and in the
+Pallas interpreter elsewhere; the platform decides, no flag does. The
+persistent compilation cache follows ``JAX_COMPILATION_CACHE_DIR`` when it
+is set, else ``<checkout>/.jax_cache`` (``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -71,14 +76,6 @@ def _parse_args():
                     help="ADC lookup-table precision (pq/ivfpq)")
     ap.add_argument("--pq-backend", choices=["jnp", "kernel"], default="jnp",
                     help="ADC scoring backend (kernel = fused Pallas scan)")
-    ap.add_argument("--interpret", dest="interpret", action="store_true",
-                    default=None,
-                    help="run the Pallas ADC kernel in interpret mode "
-                         "(CPU-safe smoke of --pq-backend kernel; the "
-                         "engine default)")
-    ap.add_argument("--no-interpret", dest="interpret", action="store_false",
-                    help="compile the Pallas ADC kernel for the real "
-                         "accelerator")
     ap.add_argument("--query-bucket", type=int, default=64,
                     help="min padded query-batch size; ragged batches round "
                          "up to powers of two and share compilations")
@@ -91,7 +88,8 @@ def _parse_args():
     ap.add_argument("--mesh", choices=["device", "host"], default="device",
                     help="mesh device source: 'device' = the real jax "
                          "devices; 'host' = simulate --shards CPU devices "
-                         "via --xla_force_host_platform_device_count")
+                         "(JAX_PLATFORMS=cpu + "
+                         "--xla_force_host_platform_device_count)")
     ap.add_argument("--donate", action="store_true",
                     help="with --shards: release the dense EngineState "
                          "once the sharded copy is placed (no 2x memory)")
@@ -163,12 +161,18 @@ def _spec_from_flags(args):
 def main():
     args = _parse_args()
     if args.shards and args.mesh == "host":
-        # must land before jax initializes its backend (first device use)
+        # must land before jax initializes its backend (first device use);
+        # the CPU platform is forced too, or a TPU host would hand out its
+        # real chips and the simulated count would never apply
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.shards}")
 
     import jax
+
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.core import MPADConfig
     from repro.data.synthetic import make_clustered
@@ -183,8 +187,6 @@ def main():
                                spread=0.4, center_scale=1.5)
     t0 = time.time()
     runtime = dict(query_bucket=args.query_bucket, fit_sample=4096)
-    if args.interpret is not None:
-        runtime["pq_interpret"] = args.interpret
     if args.stream:
         runtime["stream"] = StreamConfig(
             delta_capacity=args.delta_capacity,

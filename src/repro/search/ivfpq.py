@@ -158,8 +158,7 @@ def ivfpq_adc_scan(centroids: jax.Array, lists: jax.Array,
                    lut_w: jax.Array, cbnorm: jax.Array,
                    codebooks: jax.Array, q: jax.Array,
                    n_cand: int, nprobe: int = 8, backend: str = "jnp",
-                   interpret: bool = True, lut_dtype: str = "f32",
-                   live=None):
+                   lut_dtype: str = "f32", live=None):
     """Probe + cell-major ADC scan over raw index arrays — the shared core
     of ``ivfpq_scan`` (read-only serving) and the streaming masked scan.
 
@@ -176,8 +175,8 @@ def ivfpq_adc_scan(centroids: jax.Array, lists: jax.Array,
                                     nprobe, n_cand)       # (Q,P),(Q,C),(Q,P)
     return ivfpq_scan_given_probe(probe, cand, cd2p, codes_cell, bias_cell,
                                   lut_w, cbnorm, codebooks, q, n_cand,
-                                  backend=backend, interpret=interpret,
-                                  lut_dtype=lut_dtype, live=live)
+                                  backend=backend, lut_dtype=lut_dtype,
+                                  live=live)
 
 
 def ivfpq_scan_given_probe(probe: jax.Array, cand: jax.Array,
@@ -185,8 +184,7 @@ def ivfpq_scan_given_probe(probe: jax.Array, cand: jax.Array,
                            bias_cell: jax.Array, lut_w: jax.Array,
                            cbnorm: jax.Array, codebooks: jax.Array,
                            q: jax.Array, n_cand: int, backend: str = "jnp",
-                           interpret: bool = True, lut_dtype: str = "f32",
-                           live=None):
+                           lut_dtype: str = "f32", live=None):
     """ADC scan given an already-computed coarse probe — the back half of
     ``ivfpq_adc_scan``, split out so the deep-trace staged pipeline can
     time probe and scan as separate programs with identical math.
@@ -229,7 +227,6 @@ def ivfpq_scan_given_probe(probe: jax.Array, cand: jax.Array,
         from repro.kernels.pq_adc import pq_adc_gather_topk_pallas
         kt = tables if center is None else tables - center[:, :, None]
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
-                                            interpret=interpret,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
         adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
@@ -256,8 +253,7 @@ def ivfpq_compact_scan(centroids: jax.Array, lists: jax.Array,
                        lut_w: jax.Array, cbnorm: jax.Array,
                        codebooks: jax.Array, q: jax.Array,
                        n_cand: int, nprobe: int = 8, scan_cap: int = 128,
-                       backend: str = "jnp", interpret: bool = True,
-                       lut_dtype: str = "f32"):
+                       backend: str = "jnp", lut_dtype: str = "f32"):
     """nprobe-proportional ADC scan for small query buckets.
 
     The padded scan (``ivfpq_adc_scan``) gathers ``nprobe * max_cell``
@@ -318,7 +314,6 @@ def ivfpq_compact_scan(centroids: jax.Array, lists: jax.Array,
         from repro.kernels.pq_adc import pq_adc_gather_topk_pallas
         kt = tables if center is None else tables - center[:, :, None]
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
-                                            interpret=interpret,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
         adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
@@ -340,13 +335,12 @@ def ivfpq_compact_scan(centroids: jax.Array, lists: jax.Array,
 
 
 def ivfpq_scan(index: IVFPQIndex, q: jax.Array, k: int, nprobe: int = 8,
-               backend: str = "jnp", interpret: bool = True,
-               lut_dtype: str = "f32"):
+               backend: str = "jnp", lut_dtype: str = "f32"):
     """Unjitted ``ivfpq_search`` core (inlineable into fused programs)."""
     d2, ids = ivfpq_adc_scan(index.centroids, index.lists, index.codes_cell,
                              index.bias_cell, index.lut_w, index.cbnorm,
                              index.codebooks, q, k, nprobe, backend,
-                             interpret, lut_dtype)
+                             lut_dtype)
     return jnp.sqrt(jnp.maximum(d2, 0.0)), ids
 
 
@@ -355,8 +349,8 @@ def ivfpq_local_scan(centroids: jax.Array, lists_loc: jax.Array,
                      lut_w: jax.Array, cbnorm: jax.Array,
                      codebooks: jax.Array, q: jax.Array,
                      n_cand: int, nprobe: int, axis: str,
-                     backend: str = "jnp", interpret: bool = True,
-                     lut_dtype: str = "f32", live=None):
+                     backend: str = "jnp", lut_dtype: str = "f32",
+                     live=None):
     """Shard-local IVF-PQ probe + ADC scan (a ``shard_map`` body of sharded
     serving).
 
@@ -400,7 +394,6 @@ def ivfpq_local_scan(centroids: jax.Array, lists_loc: jax.Array,
         from repro.kernels.pq_adc import pq_adc_gather_topk_pallas
         kt = tables if center is None else tables - center[:, :, None]
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
-                                            interpret=interpret,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
         adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
@@ -422,14 +415,13 @@ def ivfpq_local_scan(centroids: jax.Array, lists_loc: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "nprobe", "backend",
-                                             "interpret", "lut_dtype"))
+                                             "lut_dtype"))
 def ivfpq_search(index: IVFPQIndex, q: jax.Array, k: int, nprobe: int = 8,
-                 backend: str = "jnp", interpret: bool = True,
-                 lut_dtype: str = "f32"):
+                 backend: str = "jnp", lut_dtype: str = "f32"):
     """Probe ``nprobe`` cells, ADC-score their residual codes, top-k.
 
     Returns (approx dists (Q, k), ids (Q, k)). ``backend="kernel"`` routes
     the candidate scoring through the fused Pallas ADC-gather kernel;
     ``lut_dtype`` quantizes the per-query residual LUT on either backend.
     """
-    return ivfpq_scan(index, q, k, nprobe, backend, interpret, lut_dtype)
+    return ivfpq_scan(index, q, k, nprobe, backend, lut_dtype)

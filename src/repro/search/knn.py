@@ -4,6 +4,10 @@
 ``knn_search_blocked`` streams the database in blocks with a running top-k so
 memory stays O(Q·(k+block)) — the jnp mirror of the Pallas kernel in
 ``repro.kernels.knn_topk``.
+
+Both are the exact oracle that recall is scored against, so their inner
+products run at ``Precision.HIGHEST``: a TPU otherwise runs an f32 matmul
+as one bf16 pass, and a recall against a bf16 oracle is not a recall.
 """
 from __future__ import annotations
 
@@ -16,10 +20,14 @@ __all__ = ["knn_scan", "knn_search", "knn_search_blocked", "masked_topk",
            "recall_at_k", "amk_accuracy"]
 
 
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def _sq_dists(q: jax.Array, x: jax.Array) -> jax.Array:
     qq = jnp.sum(q * q, axis=-1)[:, None]
     xx = jnp.sum(x * x, axis=-1)[None, :]
-    return jnp.maximum(qq + xx - 2.0 * (q @ x.T), 0.0)
+    return jnp.maximum(qq + xx - 2.0 * jnp.matmul(q, x.T, precision=_EXACT),
+                       0.0)
 
 
 def knn_scan(q: jax.Array, x: jax.Array, k: int):
@@ -82,7 +90,7 @@ def knn_search_blocked(q: jax.Array, x: jax.Array, k: int, block: int = 1024):
     def scan_block(carry, xblk):
         best_d, best_i, offset = carry
         xx = jnp.sum(xblk * xblk, axis=-1)[None, :]
-        d2 = qq + xx - 2.0 * (q @ xblk.T)                     # (Q, block)
+        d2 = qq + xx - 2.0 * jnp.matmul(q, xblk.T, precision=_EXACT)
         d2 = jnp.where(jnp.isfinite(xx), jnp.maximum(d2, 0.0), jnp.inf)
         idx = offset + jnp.arange(block)[None, :]
         cand_d = jnp.concatenate([best_d, d2], axis=1)

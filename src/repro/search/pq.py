@@ -114,7 +114,7 @@ def _check_adc_args(backend: str, lut_dtype: str):
 
 
 def pq_scan(index: PQIndex, q: jax.Array, k: int, backend: str = "jnp",
-            interpret: bool = True, lut_dtype: str = "f32"):
+            lut_dtype: str = "f32"):
     """Unjitted ``pq_search`` core (inlineable into fused programs).
 
     Only the candidate-varying table part (||cb||^2 - 2<q, cb>) goes through
@@ -134,7 +134,6 @@ def pq_scan(index: PQIndex, q: jax.Array, k: int, backend: str = "jnp",
     if backend == "kernel":
         from repro.kernels.pq_adc import pq_adc_topk_pallas
         d2, ids = pq_adc_topk_pallas(tables, index.codes, k,
-                                     interpret=interpret,
                                      lut_dtype=lut_dtype)
     else:
         scores = pq_adc_scores_ref(tables, index.codes, lut_dtype)
@@ -145,8 +144,8 @@ def pq_scan(index: PQIndex, q: jax.Array, k: int, backend: str = "jnp",
 
 def pq_local_scan(lut_w: jax.Array, cbnorm: jax.Array, codes_loc: jax.Array,
                   q: jax.Array, n_cand: int, n_real: jax.Array, axis: str,
-                  backend: str = "jnp", interpret: bool = True,
-                  lut_dtype: str = "f32", slack: int = 0,
+                  backend: str = "jnp", lut_dtype: str = "f32",
+                  slack: int = 0,
                   live=None):
     """Shard-local plain-PQ ADC scan (a ``shard_map`` body of sharded
     serving): score this shard's row block of the code matrix and return
@@ -184,7 +183,7 @@ def pq_local_scan(lut_w: jax.Array, cbnorm: jax.Array, codes_loc: jax.Array,
         from repro.kernels.pq_adc.ops import pq_adc_topk_global
         return pq_adc_topk_global(tables, codes_loc, n_cand, row_offset=off,
                                   n_valid=n_real, slack=slack,
-                                  interpret=interpret, lut_dtype=lut_dtype)
+                                  lut_dtype=lut_dtype)
     scores = pq_adc_scores_ref(tables, codes_loc, lut_dtype)
     gid = off + jnp.arange(n_loc)
     ok = gid[None, :] < n_real
@@ -197,9 +196,9 @@ def pq_local_scan(lut_w: jax.Array, cbnorm: jax.Array, codes_loc: jax.Array,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "backend", "interpret", "lut_dtype"))
+                   static_argnames=("k", "backend", "lut_dtype"))
 def pq_search(index: PQIndex, q: jax.Array, k: int, backend: str = "jnp",
-              interpret: bool = True, lut_dtype: str = "f32"):
+              lut_dtype: str = "f32"):
     """ADC top-k: returns (approx dists (Q,k), ids (Q,k)).
 
     ``backend="jnp"`` scores with vectorized table lookups; ``"kernel"``
@@ -208,4 +207,4 @@ def pq_search(index: PQIndex, q: jax.Array, k: int, backend: str = "jnp",
     quantizes the per-query tables (both backends score through the same
     quantization, so they stay parity oracles of each other).
     """
-    return pq_scan(index, q, k, backend, interpret, lut_dtype)
+    return pq_scan(index, q, k, backend, lut_dtype)

@@ -86,7 +86,6 @@ class ScanParams:
     """
     nprobe: int = 8
     backend: str = "jnp"
-    interpret: bool = True
     lut_dtype: str = "f32"
     scan_cap: int = 0
 
@@ -449,14 +448,14 @@ def _pq_build(key, reduced, spec):
 
 def _pq_scan(state, qr, n_cand, p):
     return pq_scan(state.index.payload, qr, n_cand, backend=p.backend,
-                   interpret=p.interpret, lut_dtype=p.lut_dtype)
+                   lut_dtype=p.lut_dtype)
 
 
 def _pq_local_scan(sstate, qr, n_cand, p, axis, slack, live=None):
     ix = sstate.index.payload
     return pq_local_scan(ix.lut_w, ix.cbnorm, ix.codes, qr, n_cand,
                          sstate.n_real, axis, backend=p.backend,
-                         interpret=p.interpret, lut_dtype=p.lut_dtype,
+                         lut_dtype=p.lut_dtype,
                          slack=slack, live=live)
 
 
@@ -573,14 +572,14 @@ def _opq_build(key, reduced, spec):
 def _opq_scan(state, qr, n_cand, p):
     ix = state.index.payload
     return pq_scan(_opq_pq_view(ix), qr @ ix.rot, n_cand, backend=p.backend,
-                   interpret=p.interpret, lut_dtype=p.lut_dtype)
+                   lut_dtype=p.lut_dtype)
 
 
 def _opq_local_scan(sstate, qr, n_cand, p, axis, slack, live=None):
     ix = sstate.index.payload
     return pq_local_scan(ix.lut_w, ix.cbnorm, ix.codes, qr @ ix.rot, n_cand,
                          sstate.n_real, axis, backend=p.backend,
-                         interpret=p.interpret, lut_dtype=p.lut_dtype,
+                         lut_dtype=p.lut_dtype,
                          slack=slack, live=live)
 
 
@@ -662,12 +661,11 @@ def _ivfpq_scan(state, qr, n_cand, p):
                                      ix.bias_cell, ix.lut_w, ix.cbnorm,
                                      ix.codebooks, qr,
                                      n_cand, p.nprobe, p.scan_cap,
-                                     backend=p.backend, interpret=p.interpret,
+                                     backend=p.backend,
                                      lut_dtype=p.lut_dtype)
         return jnp.sqrt(jnp.maximum(d2, 0.0)), ids
     return ivfpq_scan(ix, qr, n_cand, p.nprobe,
-                      backend=p.backend, interpret=p.interpret,
-                      lut_dtype=p.lut_dtype)
+                      backend=p.backend, lut_dtype=p.lut_dtype)
 
 
 def _ivfpq_local_scan(sstate, qr, n_cand, p, axis, slack, live=None):
@@ -675,7 +673,7 @@ def _ivfpq_local_scan(sstate, qr, n_cand, p, axis, slack, live=None):
     return ivfpq_local_scan(ix.centroids, ix.lists, ix.codes_cell,
                             ix.bias_cell, ix.lut_w, ix.cbnorm, ix.codebooks,
                             qr, n_cand, p.nprobe, axis, backend=p.backend,
-                            interpret=p.interpret, lut_dtype=p.lut_dtype,
+                            lut_dtype=p.lut_dtype,
                             live=live)
 
 
@@ -683,7 +681,7 @@ def _ivfpq_stream_scan(store, frozen, qr, n_cand, live, p):
     return ivfpq_adc_scan(frozen.centroids, store.lists, store.codes_cell,
                           store.bias_cell, frozen.lut_w, frozen.cbnorm,
                           frozen.codebooks, qr,
-                          n_cand, p.nprobe, p.backend, p.interpret,
+                          n_cand, p.nprobe, p.backend,
                           p.lut_dtype, live=live)
 
 

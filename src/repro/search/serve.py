@@ -40,11 +40,11 @@ The engine is split into a **pytree of arrays** and a **pure function**:
   Being a pytree, it shards, donates, and serialises like any other jax
   state; the ``kind`` tag is pytree aux data, so it is static under jit
   and keys compile caches through the treedef.
-* ``search_fn(state, queries, k, *, nprobe, rerank, backend, interpret,
-  lut_dtype)`` — the whole query pipeline (project -> probe ->
-  ADC/flat scan -> dedup'd masked re-rank gather -> final top-k) as one
-  traceable function. Jitted, it compiles to a **single XLA program**: no
-  Python dispatch or host syncs between stages.
+* ``search_fn(state, queries, k, *, nprobe, rerank, backend, lut_dtype)``
+  — the whole query pipeline (project -> probe -> ADC/flat scan ->
+  dedup'd masked re-rank gather -> final top-k) as one traceable function.
+  Jitted, it compiles to a **single XLA program**: no Python dispatch or
+  host syncs between stages.
 
 ``SearchEngine`` is a thin stateful wrapper: it builds ``EngineState`` once,
 owns a per-engine ``jax.jit(search_fn)`` whose cache is keyed by
@@ -128,7 +128,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import MPADConfig
@@ -146,8 +145,8 @@ __all__ = ["ServeConfig", "SearchEngine", "EngineState",
            "build_engine", "config_from_spec"]
 
 _ADC_BACKENDS = ("jnp", "kernel")
-_SEARCH_STATICS = ("k", "nprobe", "rerank", "backend", "interpret",
-                   "lut_dtype", "scan_cap", "prefilter")
+_SEARCH_STATICS = ("k", "nprobe", "rerank", "backend", "lut_dtype",
+                   "scan_cap", "prefilter")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -172,8 +171,8 @@ class ServeConfig:
     pq_subspaces: int = 8                # pq/ivfpq: code bytes per vector
     pq_centroids: int = 256              # pq/ivfpq: codebook size per subspace
     pq_backend: str = "jnp"              # ADC scoring: "jnp" | "kernel"
-    pq_interpret: bool = True            # kernel backend: Pallas interpret
-    #                                      mode (set False on real TPU)
+    #                                      (the kernel compiles on a TPU and
+    #                                      is interpreted elsewhere)
     lut_dtype: str = "f32"               # ADC LUT precision: f32 | bf16 | int8
     query_bucket: int = 64               # min padded query-batch size; ragged
     #                                      batches round up to powers of two
@@ -259,7 +258,7 @@ def config_from_spec(spec, **runtime) -> ServeConfig:
 
     ``runtime`` forwards the engine knobs a pipeline spec does not carry
     (``query_bucket``, ``small_batch``, ``mpad``, ``fit_sample``,
-    ``seed``, ``pq_interpret``, ``stream``). Round-trips with
+    ``seed``, ``stream``). Round-trips with
     ``ServeConfig.to_spec``.
     """
     if isinstance(spec, str):
@@ -438,8 +437,7 @@ def _check_rerank_budget(approximate: bool, rerank: int, k: int):
 
 def search_fn(state: EngineState, queries: jax.Array, k: int, *,
               nprobe: int = 8, rerank: int = 64, backend: str = "jnp",
-              interpret: bool = True, lut_dtype: str = "f32",
-              scan_cap: int = 0, prefilter: int = 0):
+              lut_dtype: str = "f32", scan_cap: int = 0, prefilter: int = 0):
     """The entire query pipeline as one pure traceable function.
 
     project -> probe/scan (dispatched on ``state.index.kind`` through the
@@ -471,8 +469,8 @@ def search_fn(state: EngineState, queries: jax.Array, k: int, *,
     approximate = state.proj is not None or ops.lossy
     _check_rerank_budget(approximate, rerank, k)
     n_cand = rerank if approximate else k
-    p = ScanParams(nprobe=nprobe, backend=backend, interpret=interpret,
-                   lut_dtype=lut_dtype, scan_cap=scan_cap)
+    p = ScanParams(nprobe=nprobe, backend=backend, lut_dtype=lut_dtype,
+                   scan_cap=scan_cap)
     with jax.named_scope("qpad.scan"):
         d_scan, cand = ops.scan(state, qr, n_cand, p)
     if prefilter > 0:
@@ -514,7 +512,7 @@ def _sharded_rerank(queries: jax.Array, corpus_loc: jax.Array,
 
 def _sharded_core(sstate: ShardedEngineState, queries: jax.Array, *, k: int,
                   nprobe: int, rerank: int, backend: str,
-                  interpret: bool, lut_dtype: str, axis: str, slack: int,
+                  lut_dtype: str, axis: str, slack: int,
                   scan_cap: int = 0, prefilter: int = 0):
     """The shard_map body: the full per-shard pipeline + distributed merge."""
     if scan_cap or prefilter:
@@ -530,8 +528,7 @@ def _sharded_core(sstate: ShardedEngineState, queries: jax.Array, *, k: int,
     approximate = sstate.proj is not None or ops.lossy
     _check_rerank_budget(approximate, rerank, k)
     n_cand = rerank if approximate else k
-    p = ScanParams(nprobe=nprobe, backend=backend, interpret=interpret,
-                   lut_dtype=lut_dtype)
+    p = ScanParams(nprobe=nprobe, backend=backend, lut_dtype=lut_dtype)
     with jax.named_scope("qpad.scan"):
         d2, cand = ops.local_scan(sstate, qr, n_cand, p, axis, slack)
     # distributed merge: every shard's local top-n_cand is a superset of the
@@ -550,7 +547,7 @@ def _sharded_core(sstate: ShardedEngineState, queries: jax.Array, *, k: int,
 def sharded_search_fn(sstate: ShardedEngineState, queries: jax.Array, k: int,
                       *, mesh: Mesh, axis: str = "data",
                       nprobe: int = 8, rerank: int = 64, backend: str = "jnp",
-                      interpret: bool = True, lut_dtype: str = "f32",
+                      lut_dtype: str = "f32",
                       scan_cap: int = 0, prefilter: int = 0):
     """``search_fn`` partitioned over the ``axis`` of ``mesh``.
 
@@ -563,10 +560,10 @@ def sharded_search_fn(sstate: ShardedEngineState, queries: jax.Array, k: int,
     specs = engine_state_specs(sstate, axis)
     core = functools.partial(
         _sharded_core, k=k, nprobe=nprobe, rerank=rerank,
-        backend=backend, interpret=interpret, lut_dtype=lut_dtype, axis=axis,
+        backend=backend, lut_dtype=lut_dtype, axis=axis,
         slack=mesh.shape[axis] - 1, scan_cap=scan_cap, prefilter=prefilter)
-    f = shard_map(core, mesh=mesh, in_specs=(specs, P()),
-                  out_specs=(P(), P()), check_rep=False)
+    f = jax.shard_map(core, mesh=mesh, in_specs=(specs, P()),
+                      out_specs=(P(), P()), check_vma=False)
     return f(sstate, queries)
 
 
@@ -1504,7 +1501,6 @@ class SearchEngine:
         kw = dict(nprobe=cfg.nprobe if probed else 0,
                   rerank=cfg.rerank,
                   backend=cfg.pq_backend if coded else "jnp",
-                  interpret=cfg.pq_interpret if coded else True,
                   lut_dtype=cfg.lut_dtype if coded else "f32",
                   scan_cap=0, prefilter=0)
         # small read-only ivfpq buckets: size the candidate gather by the

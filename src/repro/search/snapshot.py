@@ -58,7 +58,10 @@ SNAPSHOT_META = "engine.json"
 _SCHEMA = "qpad.engine_snapshot.v1"
 # engine knobs a pipeline spec does not carry; persisted verbatim
 _RUNTIME_FIELDS = ("query_bucket", "small_batch", "compact_batch",
-                   "prefilter_batch", "fit_sample", "seed", "pq_interpret")
+                   "prefilter_batch", "fit_sample", "seed")
+# knobs older snapshots persisted that no longer exist; ignored at load
+# (pq_interpret: the platform now picks Pallas interpret mode)
+_RETIRED_RUNTIME_FIELDS = ("pq_interpret",)
 
 
 class _Leaf:
@@ -459,7 +462,8 @@ def load_engine(directory: str, mesh: Optional[Mesh] = None,
             "stream= cannot be overridden at load: the StreamConfig's "
             "capacities are baked into the saved store's array shapes — "
             "restore, then compact/rebuild to re-provision")
-    runtime = dict(meta["runtime"])
+    runtime = {f: v for f, v in meta["runtime"].items()
+               if f not in _RETIRED_RUNTIME_FIELDS}
     if meta["stream"] is not None:
         skw = dict(meta["stream"])
         if skw.get("policy") is not None:

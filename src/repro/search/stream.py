@@ -41,7 +41,6 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .knn import _sq_dists, masked_topk
@@ -131,7 +130,7 @@ def _to_external(ids, row_ids, delta_ids):
 def stream_search_fn(store: StreamStore, frozen: FrozenParams,
                      queries: jax.Array, k: int, *,
                      nprobe: int = 8, rerank: int = 64, backend: str = "jnp",
-                     interpret: bool = True, lut_dtype: str = "f32",
+                     lut_dtype: str = "f32",
                      scan_cap: int = 0, prefilter: int = 0):
     """The mutable-engine query pipeline as one pure traceable function.
 
@@ -158,8 +157,7 @@ def stream_search_fn(store: StreamStore, frozen: FrozenParams,
     n_cand = rerank if approximate else k
     live = live_mask(store)
     n_cap = store.corpus.shape[0]
-    p = ScanParams(nprobe=nprobe, backend=backend, interpret=interpret,
-                   lut_dtype=lut_dtype)
+    p = ScanParams(nprobe=nprobe, backend=backend, lut_dtype=lut_dtype)
     with jax.named_scope("qpad.base_scan"):
         bd2, bids = ops.stream_scan(store, frozen, qr, n_cand, live, p)
     delta_scan_rows = (store.delta_reduced
@@ -183,7 +181,7 @@ def stream_search_fn(store: StreamStore, frozen: FrozenParams,
 def _stream_sharded_core(sbase: ShardedEngineState, repl: StreamReplica,
                          queries: jax.Array, *, k: int,
                          nprobe: int, rerank: int, backend: str,
-                         interpret: bool, lut_dtype: str, axis: str):
+                         lut_dtype: str, axis: str):
     """The shard_map body: masked per-shard base scan + replicated delta
     scan + distributed merge + two-source re-rank."""
     ops = get_ops(sbase.index.kind)
@@ -195,8 +193,7 @@ def _stream_sharded_core(sbase: ShardedEngineState, repl: StreamReplica,
     n_cand = rerank if approximate else k
     live = (repl.row_ids >= 0) & ~repl.dead
     n_cap = repl.row_ids.shape[0]
-    p = ScanParams(nprobe=nprobe, backend=backend, interpret=interpret,
-                   lut_dtype=lut_dtype)
+    p = ScanParams(nprobe=nprobe, backend=backend, lut_dtype=lut_dtype)
     with jax.named_scope("qpad.base_scan"):
         d2, cand = ops.local_scan(sbase, qr, n_cand, p, axis, 0, live=live)
         d2g = jax.lax.all_gather(d2, axis, axis=1, tiled=True)
@@ -236,8 +233,7 @@ def sharded_stream_search_fn(sbase: ShardedEngineState, repl: StreamReplica,
                              queries: jax.Array, k: int, *, mesh: Mesh,
                              axis: str = "data",
                              nprobe: int = 8, rerank: int = 64,
-                             backend: str = "jnp", interpret: bool = True,
-                             lut_dtype: str = "f32",
+                             backend: str = "jnp", lut_dtype: str = "f32",
                              scan_cap: int = 0, prefilter: int = 0):
     """``stream_search_fn`` with the base partitioned over ``mesh``.
 
@@ -257,7 +253,8 @@ def sharded_stream_search_fn(sbase: ShardedEngineState, repl: StreamReplica,
                                  for f in StreamReplica._fields])
     core = functools.partial(
         _stream_sharded_core, k=k, nprobe=nprobe, rerank=rerank,
-        backend=backend, interpret=interpret, lut_dtype=lut_dtype, axis=axis)
-    f = shard_map(core, mesh=mesh, in_specs=(base_specs, repl_specs, P()),
-                  out_specs=(P(), P()), check_rep=False)
+        backend=backend, lut_dtype=lut_dtype, axis=axis)
+    f = jax.shard_map(core, mesh=mesh,
+                      in_specs=(base_specs, repl_specs, P()),
+                      out_specs=(P(), P()), check_vma=False)
     return f(sbase, repl, queries)

@@ -136,7 +136,7 @@ _probe_stage = jax.jit(probe_cells, static_argnames=("nprobe", "min_cand"))
 
 _ivfpq_scan_stage = jax.jit(
     ivfpq_scan_given_probe,
-    static_argnames=("n_cand", "backend", "interpret", "lut_dtype"))
+    static_argnames=("n_cand", "backend", "lut_dtype"))
 
 
 @functools.partial(jax.jit, static_argnames=("n_cand", "p"))
@@ -178,8 +178,7 @@ def deep_trace(engine, queries, k: int, kw: Mapping) -> Optional[dict]:
     ops = get_ops(state.index.kind)
     approximate = state.proj is not None or ops.lossy
     n_cand = kw["rerank"] if approximate else k
-    statics = (kw["nprobe"], kw["backend"], kw["interpret"],
-               kw["lut_dtype"], n_cand, k)
+    statics = (kw["nprobe"], kw["backend"], kw["lut_dtype"], n_cand, k)
 
     def _run():
         stages = []
@@ -197,13 +196,11 @@ def deep_trace(engine, queries, k: int, kw: Mapping) -> Optional[dict]:
             _, cand = _block(_ivfpq_scan_stage(
                 probe, cand0, cd2p, ix.codes_cell, ix.bias_cell,
                 ix.lut_w, ix.cbnorm, ix.codebooks, qr, n_cand=n_cand,
-                backend=kw["backend"], interpret=kw["interpret"],
-                lut_dtype=kw["lut_dtype"]))
+                backend=kw["backend"], lut_dtype=kw["lut_dtype"]))
             t3 = time.perf_counter()
             stages.append(("scan", (t3 - t2) * 1e3))
         else:
             p = ScanParams(nprobe=kw["nprobe"], backend=kw["backend"],
-                           interpret=kw["interpret"],
                            lut_dtype=kw["lut_dtype"])
             _, cand = _block(_scan_stage(state, qr, n_cand=n_cand, p=p))
             t3 = time.perf_counter()

@@ -293,7 +293,7 @@ def test_recovered_store_matches_rebuild_oracle(tmp_path):
     oracle = rebuild_state(rec.frozen, surv, index=index)
     q = _queries()
     d_r, i_r = search_fn(oracle, q, K, nprobe=12, rerank=128,
-                         backend="jnp", interpret=True, lut_dtype="f32")
+                         backend="jnp", lut_dtype="f32")
     d_s, i_s = rec.search(q, K)
     np.testing.assert_array_equal(np.sort(np.asarray(i_s), axis=1),
                                   np.sort(surv_ids[np.asarray(i_r)], axis=1))

@@ -1,5 +1,6 @@
 """Per-kernel validation vs the pure-jnp oracles: shape/dtype sweeps +
-hypothesis property tests (interpret=True executes the kernel body on CPU)."""
+hypothesis property tests (on CPU the platform picks interpret mode, which
+executes the kernel body as XLA ops)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

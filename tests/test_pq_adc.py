@@ -1,5 +1,7 @@
-"""Parity tests for the fused ADC-scan Pallas kernels (interpret=True
-executes the kernel body on CPU) against the pure-jnp oracles in ref.py."""
+"""Parity tests for the fused ADC-scan Pallas kernels against the pure-jnp
+oracles in ref.py (on CPU the platform picks interpret mode, which executes
+the kernel body as XLA ops; ``tests/test_tpu_compile.py`` compiles the same
+kernels for a v5e)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

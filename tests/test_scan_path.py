@@ -101,8 +101,7 @@ def test_codes_stored_uint8():
 def test_uint8_vs_int32_parity(backend, lut):
     eng = _engine()
     q = _queries()
-    kw = dict(nprobe=8, rerank=64, backend=backend, interpret=True,
-              lut_dtype=lut)
+    kw = dict(nprobe=8, rerank=64, backend=backend, lut_dtype=lut)
     _assert_bit_identical(search_fn(eng.state, q, K, **kw),
                           search_fn(_as_int32_state(eng.state), q, K, **kw))
 
@@ -118,8 +117,7 @@ def test_uint8_vs_int32_sharded_parity(shards):
                          devices=jax.devices()[:shards])
     eng = _engine()
     q = _queries()
-    kw = dict(nprobe=8, rerank=64, backend="jnp", interpret=True,
-              lut_dtype="f32")
+    kw = dict(nprobe=8, rerank=64, backend="jnp", lut_dtype="f32")
     s8 = shard_engine(eng.state, mesh)
     s32 = shard_engine(_as_int32_state(eng.state), mesh)
     _assert_bit_identical(
@@ -145,8 +143,7 @@ def test_uint8_vs_int32_streaming_parity():
         codes=eng.store.codes.astype(jnp.int32),
         codes_cell=eng.store.codes_cell.astype(jnp.int32))
     q = _queries()
-    kw = dict(nprobe=8, rerank=64, backend="jnp", interpret=True,
-              lut_dtype="f32")
+    kw = dict(nprobe=8, rerank=64, backend="jnp", lut_dtype="f32")
     _assert_bit_identical(
         stream_search_fn(eng.store, eng.frozen, q, K, **kw),
         stream_search_fn(wide, eng.frozen, q, K, **kw))
@@ -166,12 +163,12 @@ def test_compact_scan_bit_identical(backend, lut):
     assert cap > 0, "test corpus should have skewed cells"
     d1, i1 = ivfpq_adc_scan(ix.centroids, ix.lists, ix.codes_cell,
                             ix.bias_cell, ix.lut_w, ix.cbnorm, ix.codebooks,
-                            q, 64, 8, backend, True, lut)
+                            q, 64, 8, backend, lut)
     d2, i2 = ivfpq_compact_scan(ix.centroids, ix.lists, ix.codes_cell,
                                 ix.bias_cell, ix.lut_w, ix.cbnorm,
                                 ix.codebooks, q, 64,
                                 nprobe=8, scan_cap=cap, backend=backend,
-                                interpret=True, lut_dtype=lut)
+                                lut_dtype=lut)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
 
@@ -220,8 +217,7 @@ def test_prefilter_never_drops_a_true_topk_id(seed, k, lut):
     member (ties included)."""
     eng = _engine()
     q = _data()[:8] + 0.1 * jax.random.normal(jax.random.key(seed), (8, DIM))
-    kw = dict(nprobe=8, rerank=64, backend="jnp", interpret=True,
-              lut_dtype=lut)
+    kw = dict(nprobe=8, rerank=64, backend="jnp", lut_dtype=lut)
     r_s = max(2 * k, 32)
     _assert_same_ids(
         search_fn(eng.state, q, k, prefilter=r_s, **kw),

@@ -92,7 +92,7 @@ def test_sharded_matches_single_device(index, lut, shards):
     eng = _engine(index)
     coded = index in ("pq", "ivfpq")
     kw = dict(nprobe=5, rerank=64, backend="jnp",
-              interpret=True, lut_dtype=lut if coded else "f32")
+              lut_dtype=lut if coded else "f32")
     _assert_parity(eng, kw, shards)
 
 
@@ -104,8 +104,7 @@ def test_sharded_kernel_backend_parity(index, lut):
     displacing real candidates."""
     shards = min(2, jax.device_count())
     eng = _engine(index)
-    kw = dict(nprobe=5, rerank=64, backend="kernel",
-              interpret=True, lut_dtype=lut)
+    kw = dict(nprobe=5, rerank=64, backend="kernel", lut_dtype=lut)
     _assert_parity(eng, kw, shards)
 
 

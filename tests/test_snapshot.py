@@ -189,3 +189,24 @@ def test_flat_alias_not_saved_twice(tmp_path):
     q = _queries()
     np.testing.assert_array_equal(np.asarray(eng.search(q, K)[1]),
                                   np.asarray(eng2.search(q, K)[1]))
+
+
+def test_load_ignores_retired_pq_interpret_key(tmp_path):
+    """Snapshots written while ``ServeConfig.pq_interpret`` existed carry
+    the key in ``engine.json``; they still load (the platform now picks
+    Pallas interpret mode) and serve the same ids."""
+    import json
+    import os
+    eng = _engine("ivf12x5>pq8x64@kernel")
+    eng.save(str(tmp_path))
+    meta_path = os.path.join(str(tmp_path), "engine.json")
+    with open(meta_path) as f:
+        meta = json.load(f)
+    meta["runtime"]["pq_interpret"] = True
+    with open(meta_path, "w") as f:
+        json.dump(meta, f)
+    eng2 = load_engine(str(tmp_path))
+    assert not hasattr(eng2.config, "pq_interpret")
+    q = _queries()
+    np.testing.assert_array_equal(np.asarray(eng.search(q, K)[1]),
+                                  np.asarray(eng2.search(q, K)[1]))

@@ -182,7 +182,7 @@ def test_interleaved_ops_then_compact_equals_rebuild(index, lut, target_dim,
     coded = index in ("pq", "ivfpq")
     q = _queries()
     d_r, i_r = search_fn(oracle, q, K, nprobe=12, rerank=128,
-                         backend="jnp", interpret=True,
+                         backend="jnp",
                          lut_dtype=lut if coded else "f32")
     ext_r = surv_ids[np.asarray(i_r)]
     d_s, i_s = eng.search(q, K)

@@ -54,7 +54,6 @@ def _kw(eng):
     coded = cfg.index in ("pq", "ivfpq")
     return dict(nprobe=cfg.nprobe if probed else 0, rerank=cfg.rerank,
                 backend=cfg.pq_backend if coded else "jnp",
-                interpret=cfg.pq_interpret if coded else True,
                 lut_dtype=cfg.lut_dtype if coded else "f32",
                 scan_cap=0, prefilter=0)
 
