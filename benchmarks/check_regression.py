@@ -200,9 +200,6 @@ def check_observability(baseline: dict, fresh: dict,
       instrument is off),
     * end-to-end histogram recording must cost <= ``--max-hist-overhead``
       (default 3% — a block + bisect per search, nothing device-side).
-
-    The ``latency_breakdown`` section (per-stage deep-trace shares) is
-    lost-coverage-checked against the baseline like the other sections.
     """
     failures, report = [], []
     new = fresh.get("observability")
@@ -231,10 +228,6 @@ def check_observability(baseline: dict, fresh: dict,
             f"{new['hist_overhead']:.2%} > {max_hist:.0%} "
             f"({new['p50_us_base']}us -> {new['p50_us_hist_on']}us "
             "p50 with e2e histograms on)")
-    if baseline.get("latency_breakdown") and not fresh.get(
-            "latency_breakdown"):
-        failures.append("fresh bench is missing the latency_breakdown "
-                        "section")
     return failures, report
 
 

@@ -380,13 +380,13 @@ def bench_serve_fused(rows, json_doc=None, fast=False):
             if json_doc is not None:
                 json_doc["staged_vs_fused"] = staged_rows
 
-            # --- observability overhead + per-stage breakdown ---------
+            # --- observability overhead -------------------------------
             # the overhead numbers are regression gates (<=1% with a
             # tracer attached but inert, <=3% with histograms recording)
             # so the three postures run interleaved on the SAME engine
             # and the overhead is the median per-round base/variant time
             # ratio — pairing cancels machine drift
-            from repro.search import TraceConfig, deep_trace
+            from repro.search import TraceConfig
             from repro.search.tracing import Tracer
 
             def _posture(tracer):
@@ -425,28 +425,6 @@ def bench_serve_fused(rows, json_doc=None, fast=False):
             rows.append(("serve_observability_overhead", 0.0,
                          f"traced_off={ov_off:.2%} hist_on={ov_hist:.2%} "
                          f"base_p50_us={p50_o['base']:.0f}"))
-            # per-stage attribution across the traffic range: the staged
-            # re-run deep_trace samples in production, at bench precision
-            kwd = dict(nprobe=eng.config.nprobe, rerank=eng.config.rerank,
-                       backend=eng.config.pq_backend,
-                       lut_dtype="f32", scan_cap=0, prefilter=0)
-            breakdown = []
-            for b in (1, 64, nq):
-                runs = [deep_trace(eng, queries[:b], k, kwd)
-                        for _ in range(3)]
-                names = [s for s, _ in runs[0]["stages"]]
-                med = {s: sorted(r["stages"][i][1] for r in runs)[1]
-                       for i, s in enumerate(names)}
-                e2e = sorted(r["e2e_ms"] for r in runs)[1]
-                total = sum(med.values()) or 1.0
-                shares = {s: round(ms / total, 3) for s, ms in med.items()}
-                rows.append((f"serve_latency_breakdown_b{b}", e2e * 1e3,
-                             " ".join(f"{s}={shares[s]:.0%}"
-                                      for s in names)))
-                breakdown.append(dict(
-                    index="ivfpq", batch=b, e2e_ms=round(e2e, 4),
-                    stages_ms={s: round(ms, 4) for s, ms in med.items()},
-                    shares=shares))
             if json_doc is not None:
                 json_doc["observability"] = dict(
                     index="ivfpq", batch=nq,
@@ -455,7 +433,6 @@ def bench_serve_fused(rows, json_doc=None, fast=False):
                     p50_us_hist_on=round(p50_o["hist_on"], 1),
                     trace_off_overhead=round(ov_off, 4),
                     hist_overhead=round(ov_hist, 4))
-                json_doc["latency_breakdown"] = breakdown
     if json_doc is not None:
         json_doc["rows"] = doc_rows
         json_doc["batch_sweep"] = sweep_rows

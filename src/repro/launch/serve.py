@@ -22,13 +22,14 @@ Observability: ``--metrics-port N`` serves the engine's typed metrics
 snapshot (``SearchEngine.metrics()``) from a stdlib http.server thread —
 ``GET /metrics`` is Prometheus text, ``GET /metrics.json`` the flattened
 JSON (port 0 binds an ephemeral port and prints it). Request-level
-tracing rides the same engine: ``--trace-dir DIR`` exports a
-Chrome-trace JSON of the served batches, ``--slow-query-ms T`` captures
-over-threshold queries into a ring buffer, ``--deep-trace-every N``
-re-runs 1-in-N batches through the staged pipeline for per-stage
-latency attribution, and ``--recall-every N`` shadow-checks 1-in-N
-batches against the exact scan to estimate live recall — any of these
-turns on the ``latency.*`` histograms in the scrape.
+tracing rides the same engine: ``--slow-query-ms T`` captures
+over-threshold queries into a ring buffer and ``--recall-every N``
+shadow-checks 1-in-N batches against the exact scan to estimate live
+recall — either turns on the ``latency.*`` histograms in the scrape.
+``--trace-dir DIR`` profiles the serving run with ``jax.profiler`` into
+DIR: the engine's program spans (``qpad.search.prepare`` / ``.launch``,
+``qpad.upsert``, ``qpad.compact.fold``, ...) and the device ops, on one
+clock (open the ``.xplane.pb`` in TensorBoard, Perfetto or xprof).
 
 Sharded serving: ``--shards N`` partitions the engine state over an N-way
 data mesh (``--mesh host`` simulates the N devices on the CPU backend —
@@ -43,6 +44,7 @@ is set, else ``<checkout>/.jax_cache`` (``repro.launch.compile_cache``).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import os
 import time
 
@@ -121,18 +123,13 @@ def _parse_args():
                          "background thread: /metrics (Prometheus text), "
                          "/metrics.json (JSON); 0 = ephemeral port")
     ap.add_argument("--trace-dir", default=None, metavar="DIR",
-                    help="export a Chrome-trace JSON of the served "
-                         "batches into DIR (open in chrome://tracing or "
-                         "Perfetto); implies latency histograms")
+                    help="profile the serving run into DIR with "
+                         "jax.profiler: program spans and device ops on "
+                         "one clock (an .xplane.pb)")
     ap.add_argument("--slow-query-ms", type=float, default=None, metavar="T",
                     help="capture searches slower than T ms into the "
                          "tracer's slow-query ring buffer (printed at "
                          "the end of the run)")
-    ap.add_argument("--deep-trace-every", type=int, default=0, metavar="N",
-                    help="re-run 1-in-N batches through the staged "
-                         "pipeline for exact per-stage latency "
-                         "attribution (0 = off; read-only unsharded "
-                         "engines only)")
     ap.add_argument("--recall-every", type=int, default=0, metavar="N",
                     help="shadow-check 1-in-N batches against an exact "
                          "brute-force scan and maintain the "
@@ -178,7 +175,8 @@ def main():
     from repro.data.synthetic import make_clustered
     from repro.launch.mesh import make_serving_mesh
     from repro.search import (StreamConfig, build_engine, format_spec,
-                              knn_search, load_engine, parse_spec)
+                              jax_profile, knn_search, load_engine,
+                              parse_spec)
     from repro.search.knn import recall_at_k
 
     spec = parse_spec(args.spec) if args.spec else _spec_from_flags(args)
@@ -228,24 +226,16 @@ def main():
               f"({args.corpus} rows -> ~{-(-args.corpus // args.shards)} "
               "per shard"
               + (", dense state donated" if args.donate else "") + ")")
-    tracing_on = (args.trace_dir is not None
-                  or args.slow_query_ms is not None
-                  or args.deep_trace_every or args.recall_every
+    tracing_on = (args.slow_query_ms is not None or args.recall_every
                   or args.metrics_port is not None)
     if tracing_on:
         # attach to the FINAL engine object (post durable/snapshot/shard
         # swap-outs) so the tracer sees the served programs
-        engine.tracing(trace_dir=args.trace_dir,
-                       slow_query_ms=args.slow_query_ms,
-                       deep_trace_every=args.deep_trace_every,
+        engine.tracing(slow_query_ms=args.slow_query_ms,
                        recall_every=args.recall_every)
         knobs = ["histograms"]
-        if args.trace_dir is not None:
-            knobs.append(f"trace_dir={args.trace_dir}")
         if args.slow_query_ms is not None:
             knobs.append(f"slow_query_ms={args.slow_query_ms}")
-        if args.deep_trace_every:
-            knobs.append(f"deep_trace_every={args.deep_trace_every}")
         if args.recall_every:
             knobs.append(f"recall_every={args.recall_every}")
         print(f"tracing on ({', '.join(knobs)})")
@@ -260,46 +250,51 @@ def main():
     write_s, rows_written = 0.0, 0
     next_id = args.corpus
     import numpy as np
-    for i in range(args.batches):
-        queries = corpus[jax.random.randint(
-            jax.random.fold_in(key, i), (args.batch,), 0, args.corpus)]
-        if args.stream:
-            # the 10% write leg: upsert a batch of perturbed rows under
-            # fresh ids, plus a few deletes — all served from the delta /
-            # tombstones, auto-compacting at the threshold
-            wb = args.write_batch
-            vecs = corpus[:wb] + 0.01 * jax.random.normal(
-                jax.random.fold_in(key, 1000 + i), (wb, args.dim))
+    profile = (jax_profile(args.trace_dir) if args.trace_dir is not None
+               else contextlib.nullcontext())
+    with profile:
+        for i in range(args.batches):
+            queries = corpus[jax.random.randint(
+                jax.random.fold_in(key, i), (args.batch,), 0, args.corpus)]
+            if args.stream:
+                # the 10% write leg: upsert a batch of perturbed rows under
+                # fresh ids, plus a few deletes — all served from the delta /
+                # tombstones, auto-compacting at the threshold
+                wb = args.write_batch
+                vecs = corpus[:wb] + 0.01 * jax.random.normal(
+                    jax.random.fold_in(key, 1000 + i), (wb, args.dim))
+                t0 = time.time()
+                engine.upsert(np.arange(next_id, next_id + wb), vecs)
+                if next_id > args.corpus:         # only delete rows WE streamed
+                    engine.delete(np.arange(next_id - wb,
+                                            next_id - wb + wb // 8))
+                jax.block_until_ready(engine.store.delta_count)
+                write_s += time.time() - t0
+                rows_written += wb
+                next_id += wb
             t0 = time.time()
-            engine.upsert(np.arange(next_id, next_id + wb), vecs)
-            if next_id > args.corpus:         # only delete rows WE streamed
-                engine.delete(np.arange(next_id - wb,
-                                        next_id - wb + wb // 8))
-            jax.block_until_ready(engine.store.delta_count)
-            write_s += time.time() - t0
-            rows_written += wb
-            next_id += wb
-        t0 = time.time()
-        _, ids = engine.search(queries, args.k)
-        jax.block_until_ready(ids)
-        dt = time.time() - t0
-        _, truth = knn_search(queries, corpus, args.k)
-        rec = float(recall_at_k(ids, truth))
-        total += dt
-        rec_sum += rec
-        print(f"batch {i}: {dt*1e3:7.1f} ms  recall@{args.k}={rec:.4f}")
-        if i == 0 and metrics_srv is not None and tracing_on:
-            # mid-traffic scrape: the histogram series must already be
-            # live after the first batch (the CI smoke greps for it)
-            import urllib.request
-            with urllib.request.urlopen(metrics_srv.url, timeout=5) as r:
-                mid = r.read().decode().splitlines()
-            hist = [ln for ln in mid
-                    if ln.startswith("qpad_latency_search_seconds")]
-            print(f"mid-traffic scrape: {len(mid)} lines, "
-                  f"{len(hist)} latency-histogram samples")
-            for line in hist[:3]:
-                print(f"  {line}")
+            _, ids = engine.search(queries, args.k)
+            jax.block_until_ready(ids)
+            dt = time.time() - t0
+            _, truth = knn_search(queries, corpus, args.k)
+            rec = float(recall_at_k(ids, truth))
+            total += dt
+            rec_sum += rec
+            print(f"batch {i}: {dt*1e3:7.1f} ms  recall@{args.k}={rec:.4f}")
+            if i == 0 and metrics_srv is not None and tracing_on:
+                # mid-traffic scrape: the histogram series must already be
+                # live after the first batch (the CI smoke greps for it)
+                import urllib.request
+                with urllib.request.urlopen(metrics_srv.url, timeout=5) as r:
+                    mid = r.read().decode().splitlines()
+                hist = [ln for ln in mid
+                        if ln.startswith("qpad_latency_search_seconds")]
+                print(f"mid-traffic scrape: {len(mid)} lines, "
+                      f"{len(hist)} latency-histogram samples")
+                for line in hist[:3]:
+                    print(f"  {line}")
+    if args.trace_dir is not None:
+        print(f"profile written under {args.trace_dir}")
     print(f"\nmean: {total/args.batches*1e3:.1f} ms/batch "
           f"({args.batch/(total/args.batches):.0f} qps), "
           f"recall={rec_sum/args.batches:.4f}")
@@ -332,16 +327,6 @@ def main():
             if est is not None:
                 print(f"recall estimate: {est:.4f}@{flat['recall.k']} "
                       f"({flat['recall.samples']} shadow samples)")
-        if args.deep_trace_every:
-            stages = sorted(
-                (name.split(".")[2], flat[name])
-                for name in flat
-                if name.startswith("latency.stages.")
-                and name.endswith(".p50"))
-            if stages:
-                share = ", ".join(f"{s}={ms:.2f}ms" for s, ms in stages)
-                print(f"deep-trace stage p50: {share} "
-                      f"({flat['latency.deep_traces']} samples)")
         if args.slow_query_ms is not None:
             log = engine.tracer.slow_query_log()
             print(f"slow queries (>{args.slow_query_ms}ms): "
@@ -351,9 +336,6 @@ def main():
                 print(f"  seq={entry['seq']} {entry['e2e_ms']:.2f}ms "
                       f"batch={entry['batch']} bucket={entry['bucket']} "
                       f"nprobe={entry['nprobe']} spec={entry['spec']}")
-        if args.trace_dir is not None:
-            path = engine.flush_trace()
-            print(f"trace written: {path}")
     if metrics_srv is not None:
         import urllib.request
         with urllib.request.urlopen(metrics_srv.url, timeout=5) as r:

@@ -5,8 +5,9 @@ integrates MPAD reduction, the streaming (mutable) layer on top of it,
 snapshot persistence, the durability subsystem (write-ahead log, crash
 recovery, maintenance policy), the replication layer (WAL shipping +
 follower catch-up, incremental snapshot chains, group commit), the
-typed metrics surface, and request-level tracing (latency histograms,
-sampled deep traces, slow-query capture, online recall estimation)."""
+typed metrics surface, and request-level tracing (program spans on the
+profiler's clock, latency histograms, slow-query capture, online recall
+estimation)."""
 from .knn import (knn_search, knn_search_blocked, masked_topk, recall_at_k,
                   amk_accuracy)
 from .ivf import (IVFIndex, balance_cells, build_ivf, cell_vectors,
@@ -39,7 +40,7 @@ from .metrics import (CompactMetrics, EngineInfo, EngineMetrics,
                       PolicyMetrics, RecallMetrics, ReplicationMetrics,
                       SnapshotMetrics, StreamMetrics, WalMetrics,
                       collect_metrics, render_prometheus)
-from .tracing import TraceConfig, Tracer, deep_trace, jax_profile
+from .tracing import TraceConfig, Tracer, jax_profile
 
 __all__ = [
     "knn_search", "knn_search_blocked", "masked_topk", "recall_at_k",
@@ -77,5 +78,5 @@ __all__ = [
     "HistogramSnapshot", "LatencyMetrics", "RecallMetrics",
     "collect_metrics", "render_prometheus", "MetricsServer",
     # request-level tracing
-    "TraceConfig", "Tracer", "deep_trace", "jax_profile",
+    "TraceConfig", "Tracer", "jax_profile",
 ]

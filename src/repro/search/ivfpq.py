@@ -186,8 +186,7 @@ def ivfpq_scan_given_probe(probe: jax.Array, cand: jax.Array,
                            q: jax.Array, n_cand: int, backend: str = "jnp",
                            lut_dtype: str = "f32", live=None):
     """ADC scan given an already-computed coarse probe — the back half of
-    ``ivfpq_adc_scan``, split out so the deep-trace staged pipeline can
-    time probe and scan as separate programs with identical math.
+    ``ivfpq_adc_scan``.
     """
     q = jnp.asarray(q, jnp.float32)
     nq = q.shape[0]

@@ -80,12 +80,9 @@ class HistogramSnapshot:
 class LatencyMetrics:
     """Request-latency section (present when a ``Tracer`` is attached)."""
     search: HistogramSnapshot        # latency.search.{p50,p95,p99,...}
-    stages: Mapping[str, HistogramSnapshot]  # latency.stages.<stage>.*
-    #                                  (deep-trace samples only)
     queries: int                     # latency.queries (traced searches)
     slow_queries: int                # latency.slow_queries
     slow_query_ms: Optional[float]   # latency.slow_query_ms (threshold)
-    deep_traces: int                 # latency.deep_traces
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,8 +201,8 @@ class EngineMetrics:
     def flatten(self) -> dict:
         """``{dotted_name: value}`` — the stable wire form. Histogram
         fields flatten to derived ``.p50/.p95/.p99/.count/.sum_ms``
-        entries (``latency.search.p50``, ``latency.stages.scan.p99``,
-        ...); the full bucket vectors stay behind ``histograms()``."""
+        entries (``latency.search.p50``, ...); the full bucket vectors
+        stay behind ``histograms()``."""
         out = {}
         for section in dataclasses.fields(self):
             val = getattr(self, section.name)
@@ -218,10 +215,7 @@ class EngineMetrics:
                     out.update(_hist_entries(name, v))
                 elif isinstance(v, Mapping):
                     for k in sorted(v):
-                        if isinstance(v[k], HistogramSnapshot):
-                            out.update(_hist_entries(f"{name}.{k}", v[k]))
-                        else:
-                            out[f"{name}.{k}"] = v[k]
+                        out[f"{name}.{k}"] = v[k]
                 else:
                     out[name] = v
         return out
@@ -236,13 +230,8 @@ class EngineMetrics:
                 continue
             for f in dataclasses.fields(val):
                 v = getattr(val, f.name)
-                name = f"{section.name}.{f.name}"
                 if isinstance(v, HistogramSnapshot):
-                    out[name] = v
-                elif isinstance(v, Mapping):
-                    for k in sorted(v):
-                        if isinstance(v[k], HistogramSnapshot):
-                            out[f"{name}.{k}"] = v[k]
+                    out[f"{section.name}.{f.name}"] = v
         return out
 
     def to_json(self) -> str:
@@ -267,7 +256,7 @@ _COUNTER_NAMES = frozenset((
     "wal.group_commits", "wal.replayed",
     "snapshot.full", "snapshot.incremental",
     "replication.catch_ups", "replication.records_applied",
-    "latency.queries", "latency.slow_queries", "latency.deep_traces",
+    "latency.queries", "latency.slow_queries",
     "recall.samples",
 ))
 

@@ -102,8 +102,10 @@ watches tombstone density, capacity headroom, and quantizer drift and
 triggers ``vacuum``/grow/``rebuild_quantizers`` — every decision logged
 to the WAL for deterministic replay. ``engine.metrics()`` surfaces the
 counters; ``engine.tracing()`` (``repro.search.tracing``) adds latency
-histograms, sampled deep traces, slow-query capture, and online recall
-estimation on top.
+histograms, slow-query capture, and online recall estimation on top. The
+host work of every search, write and compaction runs under named program
+spans (``qpad.search``, ``qpad.upsert``, ``qpad.compact.fold``, ...) that a
+``jax.profiler`` trace records beside the device ops.
 
 Index kinds (``IndexSpec.kind`` / ``ServeConfig.index``):
 
@@ -138,6 +140,7 @@ from .reducers import Reducer, fit_reducer, reduce_vectors
 from .registry import INDEX_KINDS, Index, ScanParams, get_ops
 from .segments import StreamConfig
 from .spec import IndexSpec, parse_spec, spec_from_config
+from .tracing import span
 
 __all__ = ["ServeConfig", "SearchEngine", "EngineState",
            "ShardedEngineState", "StreamConfig", "search_fn",
@@ -694,8 +697,6 @@ class SearchEngine:
         # observability (repro.search.tracing): None until tracing() —
         # the serve path takes zero extra work without a tracer
         self._tracer = None
-        self._deep_warm: set = set() # deep-trace stage shapes already
-        #                              compiled (never time a compile)
         # incremental snapshots (repro.search.snapshot)
         self._base_ref = None        # the chain this engine can extend:
         #                              {dir, ckpt, wal_seq, chain} of the
@@ -907,7 +908,8 @@ class SearchEngine:
         (``_wal_wait_durable``) instead of once per chunk."""
         if self._wal is None or self._replaying:
             return
-        self._wal.append(rtype, payload, wait=wait)
+        with span("qpad.wal.append"):
+            self._wal.append(rtype, payload, wait=wait)
         self._crash("wal_appended")
 
     def _wal_wait_durable(self):
@@ -967,31 +969,37 @@ class SearchEngine:
         each chunk is WAL-logged before it lands. Returns ``self``.
         """
         self._require_stream()
-        self._poll_compaction()
-        ids = np.asarray(ids, np.int32).reshape(-1)
-        vectors = np.asarray(vectors, np.float32).reshape(ids.shape[0], -1)
-        cap = self.config.stream.delta_capacity
-        point = self._compact_point()
-        b = 0
-        while b < ids.shape[0]:
-            chunk = min(ids.shape[0] - b, point)
-            if not self._replaying:
-                self._ensure_delta_room(chunk, cap, point)
-            cid, cv = ids[b:b + chunk], vectors[b:b + chunk]
-            self._wal_append(RT_UPSERT, encode_upsert(cid, cv), wait=False)
-            if self._compact_future is not None:
-                # the pending fold donated a pre-begin copy; replay this
-                # write onto the folded store at the swap
-                self._compact_tail.append(("upsert", cid.copy(), cv.copy()))
-                self._tail_rows += chunk
-            pid, pv = self._pad_write(cid, cv)
-            # dropped stays 0 by construction (the chunking above never
-            # exceeds the compact point), so it is not synced to host here
-            self.store, _ = self._upsert_program(self.store, self.frozen,
-                                                 pid, pv)
-            self._delta_used += chunk
-            b += chunk
-        self._wal_wait_durable()     # one group-commit wait per batch
+        with span("qpad.upsert"):
+            self._poll_compaction()
+            ids = np.asarray(ids, np.int32).reshape(-1)
+            vectors = np.asarray(vectors, np.float32).reshape(
+                ids.shape[0], -1)
+            cap = self.config.stream.delta_capacity
+            point = self._compact_point()
+            b = 0
+            while b < ids.shape[0]:
+                chunk = min(ids.shape[0] - b, point)
+                if not self._replaying:
+                    self._ensure_delta_room(chunk, cap, point)
+                cid, cv = ids[b:b + chunk], vectors[b:b + chunk]
+                self._wal_append(RT_UPSERT, encode_upsert(cid, cv),
+                                 wait=False)
+                if self._compact_future is not None:
+                    # the pending fold donated a pre-begin copy; replay
+                    # this write onto the folded store at the swap
+                    self._compact_tail.append(
+                        ("upsert", cid.copy(), cv.copy()))
+                    self._tail_rows += chunk
+                pid, pv = self._pad_write(cid, cv)
+                # dropped stays 0 by construction (the chunking above
+                # never exceeds the compact point), so it is not synced
+                # to host here
+                with span("qpad.upsert.launch"):
+                    self.store, _ = self._upsert_program(
+                        self.store, self.frozen, pid, pv)
+                self._delta_used += chunk
+                b += chunk
+            self._wal_wait_durable()     # one group-commit wait per batch
         return self
 
     def delete(self, ids: jax.Array):
@@ -1001,19 +1009,21 @@ class SearchEngine:
         tombstone bitmap triggers ``vacuum`` (the reclaim path deletes
         alone never had). Returns ``self``."""
         self._require_stream()
-        self._poll_compaction()
-        ids = np.asarray(ids, np.int32).reshape(-1)
-        self._wal_append(RT_DELETE, encode_delete(ids))
-        if self._compact_future is not None:
-            self._compact_tail.append(("delete", ids.copy(), None))
-        pid, _ = self._pad_write(ids)
-        self.store = self._delete_program(self.store, pid)
-        if not self._replaying and self._policy_active:
-            dead = int(jnp.sum(self.store.dead))
-            decision = self._policy.decide_delete(
-                dead=dead, allocated=int(self.store.n_rows))
-            if decision.kind == "vacuum":
-                self.vacuum()
+        with span("qpad.delete"):
+            self._poll_compaction()
+            ids = np.asarray(ids, np.int32).reshape(-1)
+            self._wal_append(RT_DELETE, encode_delete(ids))
+            if self._compact_future is not None:
+                self._compact_tail.append(("delete", ids.copy(), None))
+            pid, _ = self._pad_write(ids)
+            with span("qpad.delete.launch"):
+                self.store = self._delete_program(self.store, pid)
+            if not self._replaying and self._policy_active:
+                dead = int(jnp.sum(self.store.dead))
+                decision = self._policy.decide_delete(
+                    dead=dead, allocated=int(self.store.n_rows))
+                if decision.kind == "vacuum":
+                    self.vacuum()
         return self
 
     # --- compaction (blocking and double-buffered) ------------------------
@@ -1036,8 +1046,10 @@ class SearchEngine:
         return store, grows
 
     def _compact_task(self, store):
-        self._crash("compact_task")
-        return self._run_compact(store)
+        """The background fold, on the ``qpad-compact`` thread."""
+        with span("qpad.compact.fold"):
+            self._crash("compact_task")
+            return self._run_compact(store)
 
     def _install_compacted(self, store, grows, tail, tail_rows):
         """Re-apply the tail writes recorded during the fold, then swap
@@ -1098,7 +1110,8 @@ class SearchEngine:
         self._observe_drift()
         self._wal_append(RT_COMPACT)
         self._crash("compact_begin")
-        snapshot = jax.tree.map(jnp.array, self.store)   # the double buffer
+        with span("qpad.compact.begin"):                 # the double buffer
+            snapshot = jax.tree.map(jnp.array, self.store)
         self._compact_tail = []
         self._tail_rows = 0
         if self._compact_executor is None:
@@ -1123,7 +1136,8 @@ class SearchEngine:
             self._compact_future = None
         tail, self._compact_tail = self._compact_tail, []
         rows, self._tail_rows = self._tail_rows, 0
-        self._install_compacted(store, grows, tail, rows)
+        with span("qpad.compact.install"):
+            self._install_compacted(store, grows, tail, rows)
         return self
 
     def _poll_compaction(self):
@@ -1335,10 +1349,10 @@ class SearchEngine:
 
     def tracing(self, config=None, **knobs) -> "SearchEngine":
         """Attach request-level observability (``repro.search.tracing``):
-        latency histograms into ``metrics().latency``, optional sampled
-        deep traces (``deep_trace_every=N``), slow-query capture
-        (``slow_query_ms=T``), shadow-exact recall estimation
-        (``recall_every=N``) and Chrome-trace export (``trace_dir=``).
+        latency histograms into ``metrics().latency``, optional
+        slow-query capture (``slow_query_ms=T``) and shadow-exact recall
+        estimation (``recall_every=N``). The program spans need no
+        tracer: profile with ``repro.search.jax_profile(dir)``.
 
         Pass a ``TraceConfig`` or its fields as keyword knobs; calling
         with no arguments attaches the cheap production default
@@ -1362,29 +1376,6 @@ class SearchEngine:
     @tracer.setter
     def tracer(self, value):
         self._tracer = value
-
-    @property
-    def trace_dir(self) -> Optional[str]:
-        """Chrome-trace export directory (None = event capture off).
-        Setting it attaches/updates the tracer in place."""
-        return (self._tracer.config.trace_dir
-                if self._tracer is not None else None)
-
-    @trace_dir.setter
-    def trace_dir(self, directory: Optional[str]):
-        from .tracing import TraceConfig, Tracer
-        if self._tracer is None:
-            self._tracer = Tracer(TraceConfig(trace_dir=directory))
-        else:
-            self._tracer.config = dataclasses.replace(
-                self._tracer.config, trace_dir=directory)
-
-    def flush_trace(self, path: Optional[str] = None) -> Optional[str]:
-        """Write buffered trace events as Chrome-trace JSON; returns the
-        path (None when no tracer / event capture is attached)."""
-        if self._tracer is None:
-            return None
-        return self._tracer.flush(path)
 
     def _shard_stream_base(self):
         from repro.parallel.engine import shard_stream
@@ -1482,6 +1473,44 @@ class SearchEngine:
         power-of-two bucket (>= ``config.query_bucket``) so every batch size
         in a bucket reuses the same compilation, then sliced back to Q rows.
         """
+        with span("qpad.search"):
+            with span("qpad.search.prepare"):
+                queries, nq, kw = self._prepare(queries, k)
+                # tracing: one perf_counter read when a tracer is attached
+                # and active; with no tracer the serve path is the old one
+                tracer = self._tracer
+                t0 = (time.perf_counter()
+                      if tracer is not None and tracer.active else None)
+                if self.store is not None:
+                    self._poll_compaction()  # swap in a finished fold
+            with span("qpad.search.launch"):
+                if self.store is not None:
+                    if self._stream_sharded_base is not None:
+                        from .stream import replica_from_store
+                        repl = replica_from_store(self.store)
+                        d, ids = self._stream_sharded_program(
+                            self._stream_sharded_base, repl, queries, k,
+                            mesh=self._mesh, axis=self._shard_axis, **kw)
+                    else:
+                        d, ids = self._stream_program(
+                            self.store, self.frozen, queries, k, **kw)
+                elif self.sharded_state is not None:
+                    d, ids = self._sharded_program(
+                        self.sharded_state, queries, k, mesh=self._mesh,
+                        axis=self._shard_axis, **kw)
+                else:
+                    d, ids = self._program(self.state, queries, k, **kw)
+            if t0 is not None:
+                # blocks the result (an honest end-to-end number — the
+                # caller's own block becomes a no-op), then records/samples
+                with span("qpad.search.trace"):
+                    tracer.on_search(self, queries, nq, k, kw, t0, d, ids)
+            return d[:nq], ids[:nq]
+
+    def _prepare(self, queries, k: int):
+        """The host work before a search's program: check k, pad the batch
+        to its bucket and normalize the knobs. Returns (padded queries,
+        real rows, knobs)."""
         cfg = self.config
         ops = get_ops(cfg.index)
         # reject an unservable k eagerly (host-side, before any tracing)
@@ -1524,33 +1553,7 @@ class SearchEngine:
                 r_s = max(2 * k, cfg.rerank // 2)
                 if r_s < cfg.rerank:
                     kw["prefilter"] = r_s
-        # tracing: one perf_counter read when a tracer is attached and
-        # active; with no tracer the serve path is exactly the old one
-        tracer = self._tracer
-        t0 = (time.perf_counter()
-              if tracer is not None and tracer.active else None)
-        if self.store is not None:
-            self._poll_compaction()     # swap in a finished background fold
-            if self._stream_sharded_base is not None:
-                from .stream import replica_from_store
-                repl = replica_from_store(self.store)
-                d, ids = self._stream_sharded_program(
-                    self._stream_sharded_base, repl, queries, k,
-                    mesh=self._mesh, axis=self._shard_axis, **kw)
-            else:
-                d, ids = self._stream_program(self.store, self.frozen,
-                                              queries, k, **kw)
-        elif self.sharded_state is not None:
-            d, ids = self._sharded_program(
-                self.sharded_state, queries, k, mesh=self._mesh,
-                axis=self._shard_axis, **kw)
-        else:
-            d, ids = self._program(self.state, queries, k, **kw)
-        if t0 is not None:
-            # blocks the result (an honest end-to-end number — the
-            # caller's own block becomes a no-op), then records/samples
-            tracer.on_search(self, queries, nq, k, kw, t0, d, ids)
-        return d[:nq], ids[:nq]
+        return queries, nq, kw
 
 
 def build_engine(corpus: jax.Array, spec, **runtime) -> SearchEngine:

@@ -145,7 +145,7 @@ def test_recall_section_and_slow_query_capture():
     """Shadow-exact sampling feeds recall.estimate_at_k; a zero slow
     threshold captures every query into the ring with its knobs."""
     eng = build_engine(_data(), "ivf12x4>pq8x64>rr40").tracing(
-        recall_every=1, slow_query_ms=0.0, deep_trace_every=2)
+        recall_every=1, slow_query_ms=0.0)
     q = _rows(3, 8)
     for _ in range(4):
         eng.search(q, K)
@@ -154,8 +154,7 @@ def test_recall_section_and_slow_query_capture():
     assert 0.0 < m.recall.estimate_at_k <= 1.0
     assert m.recall.k == K
     assert m.latency.slow_queries == 4
-    assert m.latency.deep_traces == 2          # sampled 1-in-2
-    assert set(m.latency.stages) >= {"project", "probe", "scan", "rerank"}
+    assert m.latency.queries == 4
     ring = eng.tracer.slow_query_log()
     assert len(ring) == 4
     assert ring[-1]["k"] == K and ring[-1]["batch"] == 8

@@ -1,34 +1,33 @@
-"""Request-level tracing: staged deep traces, latency histograms,
-Chrome-trace export, slow-query capture, shadow-exact recall.
+"""Request-level tracing: program spans, latency histograms, slow-query
+capture, shadow-exact recall.
 
 The contracts pinned here:
 
-* **exact decomposition** — the sampled deep trace re-runs a query batch
-  through staged jitted programs with a block between stages, so the
-  per-stage intervals are ordered, non-overlapping, and sum to the
-  staged run's own end-to-end time (the acceptance bound: within 10%).
-  ivfpq decomposes as project/probe/scan/rerank, other kinds as
-  project/scan/rerank; the staged scan is the same math as the fused
-  program (``ivfpq_scan_given_probe``).
-* **zero interference** — tracing changes no results, and deep-trace
-  stage programs live in jax's global jit cache: the engine's pinned
-  ``compile_count`` never moves.
+* **program spans** — a ``jax.profiler`` trace holds ``qpad.search`` with
+  its ``.prepare`` / ``.launch`` (/ ``.trace``) children nested on the
+  calling thread, the write path's ``qpad.upsert`` / ``qpad.delete`` with
+  ``.launch`` and ``qpad.wal.append``, and a background compaction's
+  ``qpad.compact.fold`` on the worker's own host line.
+* **zero interference** — tracing and profiling change no results and
+  never move the engine's pinned ``compile_count``.
 * **honest instruments** — histogram percentiles interpolate within the
   winning log-spaced bucket; the slow-query ring trims to capacity but
-  keeps counting; Chrome-trace export is parseable JSON whose deep
-  events tile the staged span; shadow recall scores against the LIVE
-  rows (tombstone-aware on streaming engines).
+  keeps counting; shadow recall scores against the LIVE rows
+  (tombstone-aware on streaming engines).
 """
-import json
+import glob
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
-from repro.search import SearchEngine, ServeConfig, StreamConfig, TraceConfig
-from repro.search import build_engine, deep_trace
-from repro.search.tracing import LatencyHistogram, shadow_recall
+from repro.search import (DurabilityConfig, SearchEngine, ServeConfig,
+                          StreamConfig, TraceConfig, build_engine,
+                          jax_profile)
+from repro.search.tracing import LatencyHistogram, shadow_recall, span
 
 pytestmark = pytest.mark.durability
 
@@ -47,53 +46,142 @@ def _queries(n=8, seed=3):
     return jnp.asarray(np.asarray(_data(seed=seed, n=n), np.float32))
 
 
-def _kw(eng):
-    """The normalized knob dict ``search`` dispatches with."""
-    cfg = eng.config
-    probed = cfg.index in ("ivf", "ivfpq")
-    coded = cfg.index in ("pq", "ivfpq")
-    return dict(nprobe=cfg.nprobe if probed else 0, rerank=cfg.rerank,
-                backend=cfg.pq_backend if coded else "jnp",
-                lut_dtype=cfg.lut_dtype if coded else "f32",
-                scan_cap=0, prefilter=0)
+def _host_events(tmp_path, fn):
+    """Run ``fn`` under ``jax_profile`` inside a ``test.caller`` span;
+    returns the trace's ``qpad.*`` host events as (line, name, start, end)
+    in start order, and the line the caller ran on."""
+    with jax_profile(str(tmp_path)):
+        with span("test.caller"):
+            fn()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out, caller = [], None
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line, ln in enumerate(plane.lines):
+            for e in ln.events:
+                if e.name == "test.caller":
+                    caller = line
+                elif e.name.startswith("qpad."):
+                    out.append((line, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns))
+    return sorted(out, key=lambda e: e[2]), caller
 
 
-def test_deep_trace_ivfpq_decomposition():
-    """The acceptance property: four named non-overlapping stages whose
-    sum is within 10% of the staged run's measured end-to-end time."""
+def _children(events, parent):
+    """Names of the events inside ``parent`` on its line, in order."""
+    line, _, lo, hi = parent
+    return [e[1] for e in events
+            if e is not parent and e[0] == line and lo <= e[2]
+            and e[3] <= hi]
+
+
+def _one(events, name):
+    (e,) = [e for e in events if e[1] == name]
+    return e
+
+
+@pytest.mark.parametrize("traced", [False, True])
+def test_search_spans_nest_on_the_calling_thread(tmp_path, traced):
+    """A read-only search: qpad.search on the caller's line, holding
+    .prepare then .launch (then .trace, with a tracer attached only)."""
     eng = build_engine(_data(), "ivf12x4>pq8x64>rr40")
+    if traced:
+        eng.tracing()
     q = _queries()
-    eng.search(q, K)                     # warm the fused program
-    out = deep_trace(eng, q, K, _kw(eng))
-    assert out is not None
-    names = [s for s, _ in out["stages"]]
-    assert names == ["project", "probe", "scan", "rerank"]
-    assert all(ms >= 0.0 for _, ms in out["stages"])
-    total = sum(ms for _, ms in out["stages"])
-    assert out["e2e_ms"] > 0.0
-    assert abs(total - out["e2e_ms"]) <= 0.10 * out["e2e_ms"]
+    eng.search(q, K)                                 # compile outside
+    events, caller = _host_events(tmp_path, lambda: eng.search(q, K))
+    search = _one(events, "qpad.search")
+    assert search[0] == caller
+    want = ["qpad.search.prepare", "qpad.search.launch"]
+    if traced:
+        want.append("qpad.search.trace")
+    assert _children(events, search) == want
 
 
-def test_deep_trace_generic_kind_and_guards():
-    """Non-ivfpq kinds decompose as project/scan/rerank; engines without
-    a read-only unsharded state (streaming) refuse instead of lying."""
-    eng = build_engine(_data(), "ivf12x4")
-    out = deep_trace(eng, _queries(), K, _kw(eng))
-    assert [s for s, _ in out["stages"]] == ["project", "scan", "rerank"]
-    total = sum(ms for _, ms in out["stages"])
-    assert abs(total - out["e2e_ms"]) <= 0.10 * out["e2e_ms"]
-    streaming = SearchEngine(_data(), ServeConfig(
-        index="flat", stream=StreamConfig(delta_capacity=64)))
-    assert deep_trace(streaming, _queries(), K, _kw(streaming)) is None
+def _stream_engine(**stream_kw):
+    return SearchEngine(_data(), ServeConfig(
+        index="flat", rerank=128,
+        stream=StreamConfig(delta_capacity=64, **stream_kw)))
+
+
+@pytest.mark.parametrize("op,children", [
+    ("search", ["qpad.search.prepare", "qpad.search.launch"]),
+    ("upsert", ["qpad.upsert.launch"]),
+    ("delete", ["qpad.delete.launch"])])
+def test_stream_spans_nest_on_the_calling_thread(tmp_path, op, children):
+    eng = _stream_engine()
+    q = _queries()
+    calls = {"search": lambda: eng.search(q, K),
+             "upsert": lambda: eng.upsert(
+                 np.arange(600, 604, dtype=np.int32), _queries(4, 5)),
+             "delete": lambda: eng.delete(np.asarray([1, 2], np.int32))}
+    calls[op]()                                      # compile outside
+    events, caller = _host_events(tmp_path, calls[op])
+    top = _one(events, f"qpad.{op}")
+    assert top[0] == caller
+    assert _children(events, top) == children
+
+
+def test_durable_write_logs_under_its_span(tmp_path):
+    eng = _stream_engine().durable(str(tmp_path / "d"),
+                                   DurabilityConfig(fsync="batch"))
+    ids, rows = np.arange(600, 604, dtype=np.int32), _queries(4, 5)
+    eng.upsert(ids, rows)
+    events, _ = _host_events(tmp_path / "trace",
+                             lambda: eng.upsert(ids + 4, rows))
+    assert _children(events, _one(events, "qpad.upsert")) == [
+        "qpad.wal.append", "qpad.upsert.launch"]
+
+
+def test_compaction_fold_runs_on_its_own_host_line(tmp_path):
+    """A background compaction: begin and install on the caller's line,
+    the fold on the worker's. Both lines are named after the process on
+    the CPU, so the line, not its name, tells the threads apart."""
+    eng = _stream_engine(background_compact=True)
+    gate = threading.Event()
+    eng.crash_hook = lambda p: gate.wait(30) if p == "compact_task" else None
+
+    def fold():
+        eng.upsert(np.arange(600, 660, dtype=np.int32), _queries(60, 5))
+        assert eng.metrics().compact.pending
+        gate.set()
+        eng.finish_compact()
+
+    events, caller = _host_events(tmp_path, fold)
+    begin = _one(events, "qpad.compact.begin")
+    folded = _one(events, "qpad.compact.fold")
+    install = _one(events, "qpad.compact.install")
+    assert begin[0] == install[0] == caller
+    assert folded[0] != caller
+    assert begin[3] <= folded[2] and folded[3] <= install[3]
+
+
+def test_profiling_changes_no_results_or_compiles(tmp_path):
+    plain = build_engine(_data(), "ivf12x4>pq8x64>rr40")
+    profiled = build_engine(_data(), "ivf12x4>pq8x64>rr40")
+    q = _queries()
+    d0, i0 = plain.search(q, K)
+    out = {}
+
+    def run():
+        out["res"] = profiled.search(q, K)
+        out["again"] = profiled.search(q, K)
+
+    _host_events(tmp_path, run)
+    d2, i2 = profiled.search(q, K)                   # no profiler running
+    assert plain.compile_count == profiled.compile_count == 1
+    for d1, i1 in (out["res"], out["again"], (d2, i2)):
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
+        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
 
 
 def test_tracing_changes_no_results_or_compiles():
-    """Traced searches return bit-identical results, and the sampled
-    deep traces never move the engine's pinned compile_count (the stage
-    programs live in jax's global cache, not the engine's)."""
+    """Traced searches return bit-identical results, and the tracer's
+    instruments never move the engine's pinned compile_count."""
     plain = build_engine(_data(), "ivf12x4>pq8x64>rr40")
     traced = build_engine(_data(), "ivf12x4>pq8x64>rr40").tracing(
-        deep_trace_every=1, recall_every=1, slow_query_ms=0.0)
+        recall_every=1, slow_query_ms=0.0)
     q = _queries()
     d0, i0 = plain.search(q, K)
     compiles = traced.compile_count
@@ -102,7 +190,7 @@ def test_tracing_changes_no_results_or_compiles():
     assert traced.compile_count == compiles + 1    # the one fused program
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_allclose(np.asarray(d0), np.asarray(d1), rtol=1e-6)
-    assert traced.tracer.deep_traces == 3
+    assert traced.tracer.queries == 3
 
 
 def test_histogram_record_and_percentiles():
@@ -129,39 +217,11 @@ def test_histogram_record_and_percentiles():
 
 def test_traceconfig_validation():
     with pytest.raises(ValueError):
-        TraceConfig(deep_trace_every=-1)
+        TraceConfig(recall_every=-1)
     with pytest.raises(ValueError):
         TraceConfig(recall_alpha=0.0)
     with pytest.raises(ValueError):
         TraceConfig(slow_query_ms=-0.5)
-
-
-def test_chrome_trace_export(tmp_path):
-    """Events export as parseable Chrome-trace JSON; the deep-trace
-    stage events tile their search's span back-to-back; flush drains."""
-    eng = build_engine(_data(), "ivf12x4>pq8x64>rr40").tracing(
-        trace_dir=str(tmp_path / "traces"), deep_trace_every=1)
-    q = _queries()
-    for _ in range(3):
-        eng.search(q, K)
-    path = eng.flush_trace()
-    assert path is not None
-    with open(path) as f:
-        doc = json.load(f)
-    events = doc["traceEvents"]
-    searches = [e for e in events if e["name"] == "search"]
-    deep = [e for e in events if e["name"].startswith("deep.")]
-    assert len(searches) == 3 and len(deep) == 3 * 4
-    for e in events:
-        assert e["ph"] == "X" and e["dur"] >= 0.0
-    assert searches[0]["args"]["batch"] == 8
-    stage_runs = [deep[i:i + 4] for i in range(0, len(deep), 4)]
-    for run in stage_runs:                         # sequential tiling
-        for a, b in zip(run, run[1:]):
-            assert b["ts"] == pytest.approx(a["ts"] + a["dur"], abs=1e-6)
-    # the buffer drained: a second flush writes an empty event list
-    with open(eng.flush_trace()) as f:
-        assert json.load(f)["traceEvents"] == []
 
 
 def test_slow_query_ring_trims_but_keeps_counting():
@@ -226,16 +286,8 @@ def test_recall_gauge_feeds_maintenance_policy():
     assert eng.metrics().recall.samples == 3
 
 
-def test_trace_dir_property_attaches_and_updates(tmp_path):
-    eng = build_engine(_data(), "flat")
-    assert eng.trace_dir is None and eng.flush_trace() is None
-    eng.trace_dir = str(tmp_path / "t")
-    assert eng.tracer is not None and eng.tracer.active
-    eng.search(_queries(), K)
-    path = eng.flush_trace()
-    with open(path) as f:
-        assert len(json.load(f)["traceEvents"]) == 1
-    # an all-off config is inert: the serve path takes no timestamp
+def test_inert_tracer_takes_no_timestamp():
+    """An all-off config is inert: the serve path takes no timestamp."""
     idle = build_engine(_data(), "flat").tracing(histograms=False)
     assert idle.tracer.active is False
     idle.search(_queries(), K)
