@@ -8,6 +8,10 @@ Two variants mirror the two kernel entry points:
   additive ``base`` term (the IVF-PQ residual decomposition: coarse distance
   + centroid/codeword cross term; see ``repro.search.ivfpq``).
 
+The gathered variant has a second lowering with the same contract,
+``pq_adc_gather_scores_onehot``, which the IVF-PQ scans use on a TPU; the
+gather stays the oracle.
+
 Every entry takes ``lut_dtype`` (see ``lut.py``): the oracle snaps the f32
 tables onto exactly the kernel's bf16 / int8 grid but keeps the snapped
 values in f32, so the scoring gather always runs the fast f32 path — on
@@ -52,7 +56,8 @@ import jax.numpy as jnp
 from .lut import _int8_scale, snap_values
 
 __all__ = ["pq_adc_scores_ref", "pq_adc_topk_ref",
-           "pq_adc_gather_scores_ref", "pq_adc_gather_topk_ref"]
+           "pq_adc_gather_scores_ref", "pq_adc_gather_scores_onehot",
+           "pq_adc_gather_topk_ref"]
 
 
 def _resolve_scale(tables, lut_dtype, scale, center):
@@ -139,6 +144,44 @@ def pq_adc_gather_scores_ref(tables: jax.Array, codes: jax.Array,
     lut = jnp.take_along_axis(ft.reshape(nq, m * kc), flat_idx, axis=1,
                               mode="promise_in_bounds").reshape(nq, c, m)
     d2 = lut.sum(-1)
+    if lut_dtype == "int8":
+        d2 = d2 * scale[:, None]             # exact integer sums, one rescale
+    return base.astype(jnp.float32) + d2
+
+
+def pq_adc_gather_scores_onehot(tables: jax.Array, codes: jax.Array,
+                                base: jax.Array, lut_dtype: str = "f32",
+                                scale=None, center=None) -> jax.Array:
+    """``pq_adc_gather_scores_ref``'s contract, scored with no gather:
+
+    out[q, c] = base[q, c] + sum_{m, k} where(codes[q, c, m] == k,
+                                              tables[q, m, k], 0).
+
+    The TPU lowers the flattened ``take_along_axis`` to an element gather
+    that reads ~4.7 KB of HBM per f32 it returns and holds ~36 B of
+    temporaries per looked-up element (a v5e compile's cost and memory
+    analysis); a compare-select-reduce over the codebook axis is VPU work
+    that XLA fuses into one loop with no temporary. The codes are
+    transposed to (Q, M, C), so the candidate axis is the lane axis of the
+    broadcast (Q, M, K, C) operand and the reduce runs over (M, K), never
+    over lanes.
+
+    Exactly one k per (c, m) is selected, so every per-subspace term is the
+    table entry exactly; only the order of the M-term sum may differ from
+    the gather's. The grids are shared with the gather (``_snap_tables``,
+    one int8 rescale after the exact integer sums), and +inf bases stay
+    +inf. On XLA:CPU the gather is the fast form: this one does K times
+    its arithmetic.
+    """
+    tables = jnp.asarray(tables, jnp.float32)
+    kc = tables.shape[2]
+    scale = _resolve_scale(tables, lut_dtype, scale, center)
+    ft = _snap_tables(tables, lut_dtype, scale, center)
+    # compare at the stored width (uint8 for K <= 256): a widened copy of
+    # the transposed codes is a Q*C*M*4-byte temporary on the TPU
+    ct = jnp.swapaxes(codes, 1, 2)                       # (Q, M, C)
+    hit = ct[:, :, None, :] == jnp.arange(kc, dtype=ct.dtype)[:, None]
+    d2 = jnp.sum(jnp.where(hit, ft[:, :, :, None], 0.0), axis=(1, 2))
     if lut_dtype == "int8":
         d2 = d2 * scale[:, None]             # exact integer sums, one rescale
     return base.astype(jnp.float32) + d2

@@ -28,7 +28,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.pq_adc.ref import pq_adc_gather_scores_ref
+from repro.kernels.pq_adc.ref import (pq_adc_gather_scores_onehot,
+                                      pq_adc_gather_scores_ref)
 from .ivf import (_balanced_layout, kmeans, posting_lists, probe_cells,
                   sq_dists)
 from .pq import _check_adc_args, adc_tables, build_pq
@@ -97,6 +98,26 @@ def build_ivfpq(key: jax.Array, vectors: jax.Array, nlist: int,
                       bias_cell=jnp.where(lists >= 0, bias[lid], 0.0
                                           ).astype(jnp.float32),
                       lut_w=pq.lut_w, cbnorm=pq.cbnorm)
+
+
+def _adc_lowering() -> str:
+    """How the jnp backend scores gathered candidates, resolved at trace
+    time from the platform (as ``kernels.platform.resolve_interpret``
+    resolves the kernels' mode): a one-hot select-reduce on a TPU, whose
+    compiler lowers the table gather to an element gather four orders of
+    magnitude off its roofline; the gather elsewhere, since on XLA:CPU it
+    is fast and the one-hot does K times its arithmetic."""
+    return "onehot" if jax.default_backend() == "tpu" else "gather"
+
+
+def _adc_scores(tables, ccodes, base, lut_dtype, scale, center):
+    """``pq_adc_gather_scores_ref``'s contract in the lowering
+    ``_adc_lowering`` picks, under a scope that names it in traces."""
+    lowering = _adc_lowering()
+    score = (pq_adc_gather_scores_onehot if lowering == "onehot"
+             else pq_adc_gather_scores_ref)
+    with jax.named_scope(f"qpad.adc_{lowering}"):
+        return score(tables, ccodes, base, lut_dtype, scale, center)
 
 
 def ivfpq_lut_stats(codebooks: jax.Array, cbnorm: jax.Array, q: jax.Array,
@@ -228,8 +249,7 @@ def ivfpq_scan_given_probe(probe: jax.Array, cand: jax.Array,
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
-        adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
-                                       scale, center)
+        adc = _adc_scores(tables, ccodes, base, lut_dtype, scale, center)
         neg, sel = jax.lax.top_k(-adc, k_eff)
         d2 = -neg
     if center is not None:
@@ -315,8 +335,7 @@ def ivfpq_compact_scan(centroids: jax.Array, lists: jax.Array,
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
-        adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
-                                       scale, center)
+        adc = _adc_scores(tables, ccodes, base, lut_dtype, scale, center)
         neg, sel = jax.lax.top_k(-adc, k_eff)
         d2 = -neg
     if center is not None:
@@ -395,8 +414,7 @@ def ivfpq_local_scan(centroids: jax.Array, lists_loc: jax.Array,
         d2, sel = pq_adc_gather_topk_pallas(kt, ccodes, base, k_eff,
                                             lut_dtype=lut_dtype, scale=scale)
     else:
-        adc = pq_adc_gather_scores_ref(tables, ccodes, base, lut_dtype,
-                                       scale, center)
+        adc = _adc_scores(tables, ccodes, base, lut_dtype, scale, center)
         neg, sel = jax.lax.top_k(-adc, k_eff)
         d2 = -neg
     if center is not None:

@@ -8,10 +8,13 @@ import numpy as np
 import pytest
 
 from repro.kernels.pq_adc import (dequantize_lut, lut_error_bound,
+                                  pq_adc_gather_scores_onehot,
+                                  pq_adc_gather_scores_ref,
                                   pq_adc_gather_topk_pallas,
                                   pq_adc_gather_topk_ref, pq_adc_scores_ref,
                                   pq_adc_topk_pallas, pq_adc_topk_ref,
                                   quantize_lut)
+from repro.search import ivfpq
 from repro.search.pq import build_pq, pq_search
 
 pytestmark = pytest.mark.kernels
@@ -102,6 +105,79 @@ def test_gather_kernel_quantized_matches_ref(lut_dtype, atol):
     d_k, _ = pq_adc_gather_topk_pallas(tables, codes, base, 12, block_q=4,
                                        block_n=64, lut_dtype=lut_dtype)
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_ref), atol=atol)
+
+
+# --- one-hot select-reduce lowering of the gathered-codes scorer -----------
+
+@pytest.mark.parametrize("given", [False, True], ids=["derived", "given"])
+@pytest.mark.parametrize("nq,kc", [(1, 16), (1, 256), (5, 16), (5, 256),
+                                   (64, 16), (64, 256)])
+@pytest.mark.parametrize("code_dtype", [jnp.uint8, jnp.int32],
+                         ids=["uint8", "int32"])
+@pytest.mark.parametrize("lut_dtype", ["f32", "bf16", "int8"])
+def test_onehot_scores_match_gather(lut_dtype, code_dtype, nq, kc, given):
+    """The one-hot select-reduce selects each table entry exactly, so it
+    differs from the gather only in the order of the (M+1)-term sum: at
+    most M float32 ulps of sum |term| (none on the int8 grid, whose sums
+    are exact). +inf bases stay +inf, and top-k ids agree wherever the
+    k+1 best scores are not tied within that bound."""
+    m, c, k = 16, 97, 10
+    key = jax.random.key(20 + nq + kc)
+    tables = jax.random.normal(jax.random.fold_in(key, 0), (nq, m, kc)) * 3
+    codes = jax.random.randint(jax.random.fold_in(key, 1), (nq, c, m), 0,
+                               kc).astype(code_dtype)
+    base = jax.random.uniform(jax.random.fold_in(key, 2), (nq, c)) * 10
+    base = base.at[:, -5:].set(jnp.inf)          # masked posting-list pads
+    center = scale = None
+    if given:
+        center = jnp.mean(tables, axis=2)
+        if lut_dtype == "int8":                  # a looser certified bound
+            scale = 1.25 * jnp.max(jnp.abs(tables - center[:, :, None]),
+                                   axis=(1, 2)) / 127.0
+    args = (tables, codes, base, lut_dtype, scale, center)
+    want = np.asarray(pq_adc_gather_scores_ref(*args))
+    got = np.asarray(pq_adc_gather_scores_onehot(*args))
+    assert np.isinf(got[:, -5:]).all() and np.isfinite(got[:, :-5]).all()
+    want, got = want[:, :-5], got[:, :-5]
+    if lut_dtype == "int8":                      # exact integer sums
+        np.testing.assert_array_equal(got, want)
+    # sum |term| from the tables before the snap, which moves a bf16
+    # entry by under 1%
+    idx = np.asarray(codes[:, :-5]).astype(np.int64).transpose(0, 2, 1)
+    t = np.asarray(tables)
+    if center is not None and lut_dtype != "f32":
+        t = t - np.asarray(center)[:, :, None]
+    mag = (np.abs(np.take_along_axis(t, idx, axis=2)).sum(1)
+           + np.abs(np.asarray(base[:, :-5])))
+    tol = m * np.spacing((1.01 * mag).astype(np.float32))
+    assert (np.abs(got - want) <= tol).all()
+    order = np.argsort(want, axis=1, kind="stable")
+    gaps = np.diff(np.take_along_axis(want, order[:, :k + 1], axis=1), axis=1)
+    untied = (gaps > 2 * np.take_along_axis(tol, order[:, :k + 1],
+                                            axis=1)[:, 1:]).all(axis=1)
+    assert untied.any()
+    ids_w = np.asarray(jax.lax.top_k(-jnp.asarray(want), k)[1])
+    ids_g = np.asarray(jax.lax.top_k(-jnp.asarray(got), k)[1])
+    np.testing.assert_array_equal(ids_g[untied], ids_w[untied])
+
+
+def test_adc_lowering_follows_platform(monkeypatch):
+    """Gather on a CPU; one-hot when the default backend is a TPU; the
+    ``qpad.adc_*`` scope names the lowering in the compiled program."""
+    assert jax.default_backend() == "cpu"
+    assert ivfpq._adc_lowering() == "gather"
+    args = (jnp.ones((2, 4, 16)), jnp.zeros((2, 8, 4), jnp.uint8),
+            jnp.zeros((2, 8)))
+
+    def text():
+        return jax.jit(lambda t, c, b: ivfpq._adc_scores(
+            t, c, b, "f32", None, None)).lower(*args).as_text(
+                debug_info=True)
+
+    assert "qpad.adc_gather" in text() and "qpad.adc_onehot" not in text()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ivfpq._adc_lowering() == "onehot"
+    assert "qpad.adc_onehot" in text() and "qpad.adc_gather" not in text()
 
 
 def test_int8_scale_round_trip():

@@ -18,7 +18,7 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.search import SearchEngine, ServeConfig
+from repro.search import SearchEngine, ServeConfig, ivfpq
 from repro.search.ivfpq import ivfpq_adc_scan, ivfpq_compact_scan
 from repro.search.registry import Index
 from repro.search.serve import search_fn, sharded_search_fn
@@ -171,6 +171,44 @@ def test_compact_scan_bit_identical(backend, lut):
                                 lut_dtype=lut)
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+
+
+@pytest.mark.parametrize("lut", ("f32", "int8"))
+def test_compact_scan_bit_identical_onehot(lut, monkeypatch):
+    """The TPU's one-hot lowering of the jnp scorer, forced on the CPU:
+    the compact scan still reproduces the padded scan exactly."""
+    monkeypatch.setattr(ivfpq, "_adc_lowering", lambda: "onehot")
+    ix = _engine().state.index.payload
+    q = _queries()
+    cap = _engine()._scan_cap(8)
+    d1, i1 = ivfpq_adc_scan(ix.centroids, ix.lists, ix.codes_cell,
+                            ix.bias_cell, ix.lut_w, ix.cbnorm, ix.codebooks,
+                            q, 64, 8, "jnp", lut)
+    d2, i2 = ivfpq_compact_scan(ix.centroids, ix.lists, ix.codes_cell,
+                                ix.bias_cell, ix.lut_w, ix.cbnorm,
+                                ix.codebooks, q, 64, nprobe=8, scan_cap=cap,
+                                lut_dtype=lut)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_array_equal(np.asarray(d1), np.asarray(d2))
+
+
+@pytest.mark.parametrize("lut", ("f32", "int8"))
+def test_engine_onehot_lowering_same_ids(lut, monkeypatch):
+    """An engine whose program traced the one-hot lowering returns the ids
+    the gather lowering returns, through the compact (small buckets) and
+    the padded scan."""
+    gather = _engine(lut_dtype=lut)
+    qs = [_queries(nq=nq, seed=200 + nq) for nq in (1, 24, 96)]
+    want = [gather.search(q, K) for q in qs]
+    traced = []
+    monkeypatch.setattr(ivfpq, "_adc_lowering",
+                        lambda: traced.append(1) or "onehot")
+    onehot = SearchEngine(_data(), gather.config)
+    assert onehot._scan_cap(8) > 0
+    for q, w in zip(qs, want):
+        _assert_same_ids(onehot.search(q, K), w)
+    assert onehot.last_bucket > onehot.config.compact_batch
+    assert len(traced) == 3                      # buckets 1, 64 and 128
 
 
 def test_engine_compact_path_matches_defaults():

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.pq_adc import (pq_adc_gather_topk_pallas,
+from repro.kernels.pq_adc import (pq_adc_gather_scores_onehot,
+                                  pq_adc_gather_topk_pallas,
                                   pq_adc_topk_pallas)
 
 # the README pipeline's code stage, pq16x256, at a serving batch
@@ -66,3 +67,17 @@ def test_gather_kernel_compiles_for_v5e(one_chip, lut_dtype):
                        _shape(one_chip, (Q, C_GATHER), jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("lut_dtype", ["f32", "bf16", "int8"])
+def test_onehot_scorer_compiles_for_v5e_without_gather(one_chip, lut_dtype):
+    """The jnp scorer's TPU lowering: no element gather from the tables,
+    and no one-hot of Q*C*K bytes or more held in memory (the gather held
+    ~1.2 GB of temporaries at this shape)."""
+    f = jax.jit(lambda t, c, b: pq_adc_gather_scores_onehot(
+        t, c, b, lut_dtype=lut_dtype))
+    compiled = f.lower(_shape(one_chip, (Q, M, K), jnp.float32),
+                       _shape(one_chip, (Q, C_GATHER, M), jnp.uint8),
+                       _shape(one_chip, (Q, C_GATHER), jnp.float32)).compile()
+    assert "gather(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < Q * C_GATHER * K
