@@ -35,7 +35,8 @@ from .durability import (CatchUpStats, Decision, DivergenceError,
                          PolicyConfig, ReplayStats, ReplicationError, Wal,
                          WalError, WalSource, catch_up, replay,
                          replay_records, seed_follower)
-from .metrics import (CompactMetrics, EngineInfo, EngineMetrics,
+from .metrics import (BuildMetrics, CompactMetrics, EngineInfo,
+                      EngineMetrics,
                       HistogramSnapshot, LatencyMetrics, MetricsServer,
                       PolicyMetrics, RecallMetrics, ReplicationMetrics,
                       SnapshotMetrics, StreamMetrics, WalMetrics,
@@ -75,7 +76,7 @@ __all__ = [
     # typed metrics / observability
     "EngineMetrics", "EngineInfo", "StreamMetrics", "CompactMetrics",
     "PolicyMetrics", "WalMetrics", "SnapshotMetrics", "ReplicationMetrics",
-    "HistogramSnapshot", "LatencyMetrics", "RecallMetrics",
+    "HistogramSnapshot", "LatencyMetrics", "RecallMetrics", "BuildMetrics",
     "collect_metrics", "render_prometheus", "MetricsServer",
     # request-level tracing
     "TraceConfig", "Tracer", "jax_profile",

@@ -19,10 +19,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from .knn import masked_topk
+from .tracing import span
 
-__all__ = ["IVFIndex", "balance_cells", "build_ivf", "cell_vectors",
-           "ivf_local_scan", "ivf_scan", "ivf_search", "kmeans",
-           "posting_lists", "probe_cells", "sq_dists"]
+__all__ = ["BUILD_TILE_BYTES", "IVFIndex", "assign_cells", "balance_cells",
+           "block_rows", "build_ivf", "cell_vectors", "ivf_local_scan",
+           "ivf_scan", "ivf_search", "kmeans", "kmeans_ref",
+           "map_row_blocks", "posting_lists", "probe_cells", "sq_dists"]
+
+# Bytes of the (rows, k) float32 distance tile that one row block of the
+# index build computes against k centroids: k-means, the cell assignment
+# and PQ encoding walk the corpus in blocks of ``block_rows(n, k)`` rows,
+# so set-up memory is set by this tile and not by N x k.
+BUILD_TILE_BYTES = 1 << 28
 
 
 def sq_dists(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -37,9 +45,110 @@ class IVFIndex(NamedTuple):
     vectors: jax.Array      # (N, d) the stored (possibly reduced) vectors
 
 
-@functools.partial(jax.jit, static_argnames=("nlist", "iters"))
+def block_rows(n: int, k: int) -> int:
+    """Rows of one build block against ``k`` centroids: as many as keep the
+    (rows, k) f32 distance tile within ``BUILD_TILE_BYTES`` (a multiple of
+    8, at least 8), and never more than ``n``."""
+    rows = max(8, BUILD_TILE_BYTES // (4 * k) // 8 * 8)
+    return max(1, min(n, rows))
+
+
+def _block_start(b, n: int, block: int):
+    """Row where block ``b`` starts: the last block is taken back to end at
+    row ``n``, so no block reads past the array and nothing is padded."""
+    return jnp.minimum(b * block, n - block)
+
+
+def map_row_blocks(fn, n: int, block: int, *arrays):
+    """``fn`` over row blocks of ``arrays`` (each (n, ...)), its outputs
+    ((block, ...) arrays, or a tuple of them) written into (n, ...) arrays.
+
+    One ``fn`` call per block in a ``fori_loop``. The last block ends at row
+    ``n``, so the rows it shares with the block before are computed twice
+    from the same inputs and written again: ``fn`` must treat rows
+    independently.
+    """
+    shapes = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        (block,) + a.shape[1:], a.dtype) for a in arrays))
+    init = jax.tree.map(
+        lambda s: jnp.zeros((n,) + s.shape[1:], s.dtype), shapes)
+
+    def body(b, outs):
+        start = _block_start(b, n, block)
+        res = fn(*(jax.lax.dynamic_slice_in_dim(a, start, block)
+                   for a in arrays))
+        return jax.tree.map(
+            lambda o, r: jax.lax.dynamic_update_slice_in_dim(o, r, start, 0),
+            outs, res)
+
+    return jax.lax.fori_loop(0, -(-n // block), body, init)
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _assign_cells(x: jax.Array, cent: jax.Array, block: int) -> jax.Array:
+    return map_row_blocks(
+        lambda xb: jnp.argmin(sq_dists(xb, cent), axis=1).astype(jnp.int32),
+        x.shape[0], block, x)
+
+
+def assign_cells(x: jax.Array, cent: jax.Array) -> jax.Array:
+    """Nearest centroid of every row: (N,) int32, the argmin of
+    ``sq_dists`` taken in row blocks of ``block_rows``."""
+    return _assign_cells(x, cent, block_rows(x.shape[0], cent.shape[0]))
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _lloyd_step(x: jax.Array, cent: jax.Array, block: int) -> jax.Array:
+    """One Lloyd step over all rows of ``x``, in row blocks: distances,
+    argmin, counts and sums per block, accumulated."""
+    n, d = x.shape
+    nlist = cent.shape[0]
+    rows = jnp.arange(block)
+
+    def body(b, acc):
+        sums, counts = acc
+        start = _block_start(b, n, block)
+        xb = jax.lax.dynamic_slice_in_dim(x, start, block)
+        assign = jnp.argmin(sq_dists(xb, cent), axis=1)
+        # rows the block before already counted go to no cluster
+        assign = jnp.where(start + rows >= b * block, assign, nlist)
+        one_hot = jax.nn.one_hot(assign, nlist, dtype=x.dtype)
+        return sums + one_hot.T @ xb, counts + one_hot.sum(0)
+
+    sums, counts = jax.lax.fori_loop(
+        0, -(-n // block), body,
+        (jnp.zeros((nlist, d), x.dtype), jnp.zeros((nlist,), x.dtype)))
+    new = sums / jnp.maximum(counts, 1.0)[:, None]
+    # keep old centroid for empty clusters
+    return jnp.where((counts > 0)[:, None], new, cent)
+
+
+@functools.partial(jax.jit, static_argnames=("nlist",))
+def _kmeans_init(key: jax.Array, x: jax.Array, nlist: int) -> jax.Array:
+    return x[jax.random.choice(key, x.shape[0], (nlist,), replace=False)]
+
+
 def kmeans(key: jax.Array, x: jax.Array, nlist: int, iters: int = 12):
-    """Lloyd k-means with k-means++-ish random restarts on empty clusters."""
+    """Lloyd k-means with k-means++-ish random restarts on empty clusters.
+
+    ``kmeans_ref``'s algorithm over all N rows, with each step's distances,
+    argmin, counts and sums taken in row blocks of ``block_rows(N, nlist)``
+    and accumulated, so no (N, nlist) array is made. Each step is its own
+    program: inside one loop over the steps the TPU compiler re-lays ``x``
+    out row-major, which pads a (N, 32) f32 corpus to four times its size.
+    """
+    block = block_rows(x.shape[0], nlist)
+    cent = _kmeans_init(key, x, nlist)
+    for _ in range(iters):
+        cent = _lloyd_step(x, cent, block)
+    return cent
+
+
+@functools.partial(jax.jit, static_argnames=("nlist", "iters"))
+def kmeans_ref(key: jax.Array, x: jax.Array, nlist: int, iters: int = 12):
+    """``kmeans`` over the whole corpus at once: (N, nlist) distances and
+    one-hot in every step. The plain reference the blocked build is tested
+    against."""
     n = x.shape[0]
     init = jax.random.choice(key, n, (nlist,), replace=False)
     cent = x[init]
@@ -134,11 +243,14 @@ def build_ivf(key: jax.Array, vectors: jax.Array, nlist: int,
     """``balance`` (with ``shards > 1``) permutes cells so shard blocks
     carry near-equal posting mass — see ``balance_cells``."""
     vectors = jnp.asarray(vectors, jnp.float32)
-    cent = kmeans(key, vectors, nlist, kmeans_iters)
-    assign = jnp.argmin(sq_dists(vectors, cent), axis=1)  # (N,)
-    if balance and shards > 1:
-        cent, assign = _balanced_layout(cent, assign, nlist, shards)
-    lists = posting_lists(assign, nlist, shards)
+    with span("qpad.build.coarse"):
+        cent = kmeans(key, vectors, nlist, kmeans_iters)
+    with span("qpad.build.assign"):
+        assign = assign_cells(vectors, cent)              # (N,)
+    with span("qpad.build.layout"):
+        if balance and shards > 1:
+            cent, assign = _balanced_layout(cent, assign, nlist, shards)
+        lists = posting_lists(assign, nlist, shards)
     return IVFIndex(centroids=cent, lists=lists, vectors=vectors)
 
 
@@ -165,9 +277,10 @@ def probe_cells(centroids: jax.Array, lists: jax.Array, q: jax.Array,
     larger fused programs; ``probe`` lets them gather any cell-major
     per-vector payload (codes, bias, vectors) with contiguous row gathers.
     """
-    cd2 = sq_dists(q, centroids)                          # (Q, nlist)
-    _, probe = jax.lax.top_k(-cd2, nprobe)                # (Q, nprobe)
-    cd2p = jnp.take_along_axis(cd2, probe, axis=1)        # (Q, nprobe)
+    with jax.named_scope("qpad.probe"):
+        cd2 = sq_dists(q, centroids)                      # (Q, nlist)
+        _, probe = jax.lax.top_k(-cd2, nprobe)            # (Q, nprobe)
+        cd2p = jnp.take_along_axis(cd2, probe, axis=1)    # (Q, nprobe)
     cand = lists[probe].reshape(q.shape[0], -1)           # (Q, nprobe*max_cell)
     if cand.shape[1] < min_cand:
         cand = jnp.pad(cand, ((0, 0), (0, min_cand - cand.shape[1])),
@@ -206,8 +319,9 @@ def ivf_local_scan(centroids: jax.Array, lists_loc: jax.Array,
     the local top-k, so dead rows never crowd out live candidates.
     """
     q = jnp.asarray(q, jnp.float32)
-    cd2 = sq_dists(q, centroids)                          # (Q, nlist)
-    _, probe = jax.lax.top_k(-cd2, nprobe)                # global cell ids
+    with jax.named_scope("qpad.probe"):
+        cd2 = sq_dists(q, centroids)                      # (Q, nlist)
+        _, probe = jax.lax.top_k(-cd2, nprobe)            # global cell ids
     nl_loc = lists_loc.shape[0]
     coff = jax.lax.axis_index(axis) * nl_loc
     lp = probe - coff

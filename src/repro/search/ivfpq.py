@@ -30,11 +30,13 @@ import jax.numpy as jnp
 
 from repro.kernels.pq_adc.ref import (pq_adc_gather_scores_onehot,
                                       pq_adc_gather_scores_ref)
-from .ivf import (_balanced_layout, kmeans, posting_lists, probe_cells,
-                  sq_dists)
-from .pq import _check_adc_args, adc_tables, build_pq
+from .ivf import (_balanced_layout, block_rows, kmeans, map_row_blocks,
+                  posting_lists, probe_cells, sq_dists)
+from .pq import (_check_adc_args, _code_dtype, _encode_block_rows,
+                 _nearest_codes, adc_tables, lut_projection, train_codebooks)
+from .tracing import span
 
-__all__ = ["IVFPQIndex", "build_ivfpq", "ivfpq_adc_scan",
+__all__ = ["IVFPQIndex", "build_ivfpq", "encode_residuals", "ivfpq_adc_scan",
            "ivfpq_compact_scan", "ivfpq_local_scan", "ivfpq_lut_stats",
            "ivfpq_scan", "ivfpq_search"]
 
@@ -56,11 +58,61 @@ class IVFPQIndex(NamedTuple):
     cbnorm: jax.Array       # (M, K) residual codeword squared norms
 
 
+@functools.partial(jax.jit, static_argnames=("block",))
+def _assign_residuals(x, cent, block):
+    """Nearest centroid of each row and the row's residual to it, in row
+    blocks: ((N,) int32, (N, d) f32)."""
+    def blk(xb):
+        a = jnp.argmin(sq_dists(xb, cent), axis=1).astype(jnp.int32)
+        return a, xb - cent[a]
+    return map_row_blocks(blk, x.shape[0], block, x)
+
+
+def encode_residuals(codebooks, cent, residuals, assign):
+    """Codes (int32) of residual rows whose cells are ``assign``, with each
+    row's centroid/codeword cross term ``bias`` and reconstruction error
+    ``rerr`` (module docstring): the one encoding of build time and of
+    compaction, so a row gets the same payload from either."""
+    m, _, dsub = codebooks.shape
+    codes = _nearest_codes(codebooks, residuals)          # (B, M)
+    recon = jnp.take_along_axis(
+        codebooks[None], codes[:, :, None, None], axis=2
+    )[:, :, 0, :]                                         # (B, M, dsub)
+    csub = cent[assign].reshape(-1, m, dsub)
+    bias = 2.0 * jnp.sum(csub * recon, axis=(1, 2))
+    rerr = jnp.sqrt(jnp.sum(
+        (residuals - recon.reshape(residuals.shape)) ** 2, axis=1))
+    return codes, bias, rerr
+
+
+@functools.partial(jax.jit, static_argnames=("block", "code_dt"))
+def _encode_blocks(codebooks, cent, residuals, assign, block, code_dt):
+    """``encode_residuals`` in row blocks, codes stored as ``code_dt``."""
+    def blk(rb, ab):
+        codes, bias, rerr = encode_residuals(codebooks, cent, rb, ab)
+        return codes.astype(code_dt), bias, rerr
+    return map_row_blocks(blk, residuals.shape[0], block, residuals, assign)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "code_dt"))
+def _cell_mirrors(lists, codes, bias, block, code_dt):
+    """Cell-major ``codes_cell`` and ``bias_cell`` in blocks of cells."""
+    def blk(lb):
+        lid = jnp.maximum(lb, 0)
+        return (codes[lid].astype(code_dt),
+                jnp.where(lb >= 0, bias[lid], 0.0).astype(jnp.float32))
+    return map_row_blocks(blk, lists.shape[0], block, lists)
+
+
 def build_ivfpq(key: jax.Array, vectors: jax.Array, nlist: int,
                 m_subspaces: int = 8, n_centroids: int = 256,
                 kmeans_iters: int = 12, pq_iters: int = 10,
                 shards: int = 1, balance: bool = True) -> IVFPQIndex:
     """Coarse k-means, then per-subspace codebooks on the residuals.
+
+    Every pass over the rows (k-means, assignment, codebook training,
+    encoding) runs in row blocks (``ivf.block_rows``), so no (N, nlist) or
+    (N, K) array is made and set-up fits where the index does.
 
     ``shards`` pads the cell axis of the cell-major serving mirrors
     (``lists``/``codes_cell``/``bias_cell``) to per-shard-equal shapes
@@ -71,33 +123,34 @@ def build_ivfpq(key: jax.Array, vectors: jax.Array, nlist: int,
     way.
     """
     vectors = jnp.asarray(vectors, jnp.float32)
-    n, d = vectors.shape
-    cent = kmeans(key, vectors, nlist, kmeans_iters)
-    assign = jnp.argmin(sq_dists(vectors, cent), axis=1)  # (N,)
-    if balance and shards > 1:
-        cent, assign = _balanced_layout(cent, assign, nlist, shards)
-    lists = posting_lists(assign, nlist, shards)
-    residuals = vectors - cent[assign]
-    pq = build_pq(jax.random.fold_in(key, 7), residuals,
-                  m_subspaces, n_centroids, pq_iters)
-    # per-id centroid/codeword cross term (see module docstring)
-    dsub = d // m_subspaces
-    csub = cent[assign].reshape(n, m_subspaces, dsub)     # (N, M, dsub)
-    recon = jnp.take_along_axis(
-        pq.codebooks[None], pq.codes[:, :, None, None], axis=2
-    )[:, :, 0, :]                                         # (N, M, dsub)
-    bias = 2.0 * jnp.sum(csub * recon, axis=(1, 2))       # (N,)
-    rerr = jnp.sqrt(jnp.sum(
-        (residuals - recon.reshape(n, d)) ** 2, axis=1))  # (N,) ||x - x̂||
-    lid = jnp.maximum(lists, 0)
-    code_dt = jnp.uint8 if pq.codebooks.shape[1] <= 256 else jnp.int32
-    return IVFPQIndex(centroids=cent, lists=lists, codebooks=pq.codebooks,
-                      codes=pq.codes, bias=bias.astype(jnp.float32),
-                      rerr=rerr.astype(jnp.float32),
-                      codes_cell=pq.codes[lid].astype(code_dt),
-                      bias_cell=jnp.where(lists >= 0, bias[lid], 0.0
-                                          ).astype(jnp.float32),
-                      lut_w=pq.lut_w, cbnorm=pq.cbnorm)
+    n = vectors.shape[0]
+    with span("qpad.build.coarse"):
+        cent = kmeans(key, vectors, nlist, kmeans_iters)
+    with span("qpad.build.assign"):
+        assign, residuals = _assign_residuals(vectors, cent,
+                                              block_rows(n, nlist))
+    with span("qpad.build.codebooks"):
+        cbs = train_codebooks(jax.random.fold_in(key, 7), residuals,
+                              m_subspaces, n_centroids, pq_iters)
+    with span("qpad.build.encode"):
+        codes, bias, rerr = _encode_blocks(
+            cbs, cent, residuals, assign, _encode_block_rows(n, cbs),
+            _code_dtype(n_centroids))
+    del residuals
+    with span("qpad.build.layout"):
+        # the permutation moves cells only: residuals and bias are unchanged
+        if balance and shards > 1:
+            cent, assign = _balanced_layout(cent, assign, nlist, shards)
+        lists = posting_lists(assign, nlist, shards)
+        nl, max_cell = lists.shape
+        codes_cell, bias_cell = _cell_mirrors(
+            lists, codes, bias, block_rows(nl, max_cell * m_subspaces),
+            _code_dtype(cbs.shape[1]))
+    lut_w, cbnorm = lut_projection(cbs)
+    return IVFPQIndex(centroids=cent, lists=lists, codebooks=cbs,
+                      codes=codes, bias=bias, rerr=rerr,
+                      codes_cell=codes_cell, bias_cell=bias_cell,
+                      lut_w=lut_w, cbnorm=cbnorm)
 
 
 def _adc_lowering() -> str:
@@ -299,9 +352,10 @@ def ivfpq_compact_scan(centroids: jax.Array, lists: jax.Array,
     q = jnp.asarray(q, jnp.float32)
     nq = q.shape[0]
     m, kc = cbnorm.shape
-    cd2 = sq_dists(q, centroids)                          # (Q, nlist)
-    _, probe = jax.lax.top_k(-cd2, nprobe)                # probe_cells order
-    cd2p = jnp.take_along_axis(cd2, probe, axis=1)
+    with jax.named_scope("qpad.probe"):
+        cd2 = sq_dists(q, centroids)                      # (Q, nlist)
+        _, probe = jax.lax.top_k(-cd2, nprobe)            # probe_cells order
+        cd2p = jnp.take_along_axis(cd2, probe, axis=1)
     tables = adc_tables(lut_w, cbnorm, q)
     lens = jnp.sum(lists >= 0, axis=1).astype(jnp.int32)  # (nlist,) mass
     plens = lens[probe]                                   # (Q, P)
@@ -386,9 +440,10 @@ def ivfpq_local_scan(centroids: jax.Array, lists_loc: jax.Array,
     q = jnp.asarray(q, jnp.float32)
     nq = q.shape[0]
     m, kc = cbnorm.shape
-    cd2 = sq_dists(q, centroids)                          # (Q, nlist)
-    _, probe = jax.lax.top_k(-cd2, nprobe)                # global cell ids
-    cd2p = jnp.take_along_axis(cd2, probe, axis=1)
+    with jax.named_scope("qpad.probe"):
+        cd2 = sq_dists(q, centroids)                      # (Q, nlist)
+        _, probe = jax.lax.top_k(-cd2, nprobe)            # global cell ids
+        cd2p = jnp.take_along_axis(cd2, probe, axis=1)
     tables = adc_tables(lut_w, cbnorm, q)
     nl_loc = lists_loc.shape[0]
     coff = jax.lax.axis_index(axis) * nl_loc

@@ -38,11 +38,11 @@ import threading
 import time
 from typing import Mapping, Optional, Tuple
 
-__all__ = ["EngineInfo", "StreamMetrics", "CompactMetrics", "PolicyMetrics",
-           "WalMetrics", "SnapshotMetrics", "ReplicationMetrics",
-           "HistogramSnapshot", "LatencyMetrics", "RecallMetrics",
-           "EngineMetrics", "collect_metrics", "render_prometheus",
-           "MetricsServer"]
+__all__ = ["EngineInfo", "BuildMetrics", "StreamMetrics", "CompactMetrics",
+           "PolicyMetrics", "WalMetrics", "SnapshotMetrics",
+           "ReplicationMetrics", "HistogramSnapshot", "LatencyMetrics",
+           "RecallMetrics", "EngineMetrics", "collect_metrics",
+           "render_prometheus", "MetricsServer"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,6 +184,25 @@ class ReplicationMetrics:
 
 
 @dataclasses.dataclass(frozen=True)
+class BuildMetrics:
+    """How the index was built: the row blocks of its passes and the fill
+    of its cells (engines built here; None once restored from a
+    snapshot). Cell fields are None for indexes with no coarse
+    quantizer, block fields for those with no blocked pass (flat)."""
+    rows: int                        # build.rows (rows indexed)
+    block_rows: Optional[int]        # build.block_rows (rows of one block
+    #                                  of the coarse k-means, else of the
+    #                                  PQ codebooks' k-means)
+    blocks: Optional[int]            # build.blocks (blocks a pass takes)
+    nlist: Optional[int]             # build.nlist
+    max_cell: Optional[int]          # build.max_cell (fullest cell's rows)
+    mean_cell: Optional[float]       # build.mean_cell (rows / nlist)
+    padded_width: Optional[int]      # build.padded_width (nprobe x
+    #                                  max_cell: the slots the padded scan
+    #                                  scores for each query)
+
+
+@dataclasses.dataclass(frozen=True)
 class EngineMetrics:
     """One engine's full metrics snapshot. Sections that do not apply
     (a read-only engine has no ``stream``; a primary has no
@@ -197,6 +216,7 @@ class EngineMetrics:
     replication: Optional[ReplicationMetrics] = None
     latency: Optional[LatencyMetrics] = None
     recall: Optional[RecallMetrics] = None
+    build: Optional[BuildMetrics] = None
 
     def flatten(self) -> dict:
         """``{dotted_name: value}`` — the stable wire form. Histogram
@@ -397,10 +417,19 @@ def collect_metrics(engine) -> EngineMetrics:
     tracer = getattr(engine, "_tracer", None)
     if tracer is not None:
         latency, recall = tracer.metrics_sections()
+    build = None
+    rec = getattr(engine, "_build", None)
+    if rec is not None:
+        cells = rec["nlist"] is not None
+        build = BuildMetrics(
+            **rec,
+            mean_cell=rec["rows"] / rec["nlist"] if cells else None,
+            padded_width=(engine.config.nprobe * rec["max_cell"] if cells
+                          else None))
     return EngineMetrics(engine=info, stream=stream, compact=compact,
                          policy=policy, wal=wal, snapshot=snapshot,
                          replication=replication, latency=latency,
-                         recall=recall)
+                         recall=recall, build=build)
 
 
 class MetricsServer:

@@ -18,11 +18,13 @@ import jax.numpy as jnp
 
 from repro.kernels.pq_adc.lut import LUT_DTYPES, center_lut
 from repro.kernels.pq_adc.ref import pq_adc_scores_ref
-from .ivf import kmeans, sq_dists
+from .ivf import block_rows, kmeans, kmeans_ref, map_row_blocks, sq_dists
 from .knn import masked_topk
+from .tracing import span
 
-__all__ = ["PQIndex", "adc_tables", "build_pq", "lut_projection",
-           "pq_local_scan", "pq_scan", "pq_search", "pq_reconstruct"]
+__all__ = ["PQIndex", "adc_tables", "build_pq", "build_pq_ref", "encode_rows",
+           "lut_projection", "pq_local_scan", "pq_scan", "pq_search",
+           "pq_reconstruct", "train_codebooks"]
 
 
 class PQIndex(NamedTuple):
@@ -72,29 +74,92 @@ def adc_tables(lut_w: jax.Array, cbnorm: jax.Array, q: jax.Array) -> jax.Array:
     return cbnorm[None] + t.transpose(1, 0, 2)
 
 
-def build_pq(key: jax.Array, x: jax.Array, m_subspaces: int = 8,
-             n_centroids: int = 256, iters: int = 10) -> PQIndex:
-    """Train per-subspace codebooks and encode the corpus."""
-    x = jnp.asarray(x, jnp.float32)
-    n, d = x.shape
+def _code_dtype(n_centroids: int):
+    # uint8 code storage end-to-end: both scoring backends gather the
+    # narrow codes and widen in-register, so 4x fewer candidate bytes move
+    return jnp.uint8 if n_centroids <= 256 else jnp.int32
+
+
+def _subspaces(d: int, m_subspaces: int) -> int:
     if d % m_subspaces:
         raise ValueError(f"dim {d} not divisible by M={m_subspaces}")
-    dsub = d // m_subspaces
+    return d // m_subspaces
+
+
+def train_codebooks(key: jax.Array, x: jax.Array, m_subspaces: int = 8,
+                    n_centroids: int = 256, iters: int = 10) -> jax.Array:
+    """Per-subspace k-means codebooks over all rows of ``x``: (M, K', dsub)
+    with K' = min(K, N), each subspace trained by the blocked ``kmeans``."""
+    n, d = x.shape
+    dsub = _subspaces(d, m_subspaces)
+    return jnp.stack([
+        kmeans(jax.random.fold_in(key, m), x[:, m * dsub:(m + 1) * dsub],
+               min(n_centroids, n), iters)
+        for m in range(m_subspaces)])
+
+
+def _nearest_codes(codebooks: jax.Array, xb: jax.Array) -> jax.Array:
+    m, _, dsub = codebooks.shape
+    return jnp.stack([
+        jnp.argmin(sq_dists(xb[:, j * dsub:(j + 1) * dsub], codebooks[j]),
+                   axis=1) for j in range(m)], axis=1)   # M small: unrolled
+
+
+def _encode_block_rows(n: int, codebooks: jax.Array) -> int:
+    """Rows of one encoding block: its M distance tiles together keep
+    within the build's tile budget."""
+    m, kc, _ = codebooks.shape
+    return block_rows(n, m * kc)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "code_dt"))
+def _encode_rows(codebooks, x, block, code_dt):
+    return map_row_blocks(
+        lambda xb: _nearest_codes(codebooks, xb).astype(code_dt),
+        x.shape[0], block, x)
+
+
+def encode_rows(codebooks: jax.Array, x: jax.Array, n_centroids: int = 256
+                ) -> jax.Array:
+    """Nearest-codeword codes of every row of ``x`` in row blocks: (N, M),
+    uint8 for ``n_centroids`` <= 256 else int32."""
+    block = _encode_block_rows(x.shape[0], codebooks)
+    return _encode_rows(codebooks, x, block, _code_dtype(n_centroids))
+
+
+def build_pq(key: jax.Array, x: jax.Array, m_subspaces: int = 8,
+             n_centroids: int = 256, iters: int = 10) -> PQIndex:
+    """Train per-subspace codebooks and encode the corpus, both in row
+    blocks (``train_codebooks``, ``encode_rows``)."""
+    x = jnp.asarray(x, jnp.float32)
+    with span("qpad.build.codebooks"):
+        cbs = train_codebooks(key, x, m_subspaces, n_centroids, iters)
+    with span("qpad.build.encode"):
+        codes = encode_rows(cbs, x, n_centroids)
+    lut_w, cbnorm = lut_projection(cbs)
+    return PQIndex(codebooks=cbs, codes=codes, lut_w=lut_w, cbnorm=cbnorm)
+
+
+def build_pq_ref(key: jax.Array, x: jax.Array, m_subspaces: int = 8,
+                 n_centroids: int = 256, iters: int = 10) -> PQIndex:
+    """``build_pq`` over the whole corpus at once (``kmeans_ref``, and one
+    (N, K) argmin a subspace): the plain reference of the blocked build."""
+    x = jnp.asarray(x, jnp.float32)
+    n, d = x.shape
+    dsub = _subspaces(d, m_subspaces)
     xs = x.reshape(n, m_subspaces, dsub)
     cbs, codes = [], []
     for m in range(m_subspaces):
         sub = xs[:, m]
-        cb = kmeans(jax.random.fold_in(key, m), sub,
-                    min(n_centroids, n), iters)
+        cb = kmeans_ref(jax.random.fold_in(key, m), sub,
+                        min(n_centroids, n), iters)
         cbs.append(cb)
         codes.append(jnp.argmin(sq_dists(sub, cb), axis=1))
     cbs = jnp.stack(cbs)
     lut_w, cbnorm = lut_projection(cbs)
-    # uint8 code storage end-to-end: both scoring backends gather the
-    # narrow codes and widen in-register, so 4x fewer candidate bytes move
-    code_dt = jnp.uint8 if n_centroids <= 256 else jnp.int32
     return PQIndex(codebooks=cbs,
-                   codes=jnp.stack(codes, axis=1).astype(code_dt),
+                   codes=jnp.stack(codes, axis=1).astype(
+                       _code_dtype(n_centroids)),
                    lut_w=lut_w, cbnorm=cbnorm)
 
 

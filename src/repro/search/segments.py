@@ -62,6 +62,8 @@ import jax.numpy as jnp
 
 from .durability.policy import PolicyConfig
 from .ivf import sq_dists
+from .ivfpq import encode_residuals
+from .pq import _nearest_codes
 from .reducers import Reducer, reduce_vectors, reducer_dim
 from .registry import Index, _pad_cells, _pad_rows, get_ops
 
@@ -188,11 +190,7 @@ def encode_pq(codebooks: jax.Array, x: jax.Array) -> jax.Array:
     The same argmin as ``build_pq``'s final assignment, so a vector encodes
     to identical codes whether it arrived at build time or at compaction.
     """
-    m, kc, dsub = codebooks.shape
-    xs = x.reshape(x.shape[0], m, dsub)
-    codes = [jnp.argmin(sq_dists(xs[:, j], codebooks[j]), axis=1)
-             for j in range(m)]                         # M small: unrolled
-    return jnp.stack(codes, axis=1).astype(jnp.int32)
+    return _nearest_codes(codebooks, x).astype(jnp.int32)
 
 
 def ivfpq_encode(centroids: jax.Array, codebooks: jax.Array, x: jax.Array):
@@ -200,15 +198,10 @@ def ivfpq_encode(centroids: jax.Array, codebooks: jax.Array, x: jax.Array):
     quantizers. Returns (assign (B,), codes (B, M) int32, bias (B,) f32) —
     the exact per-row payload ``build_ivfpq`` computes at build time.
     """
-    m, kc, dsub = codebooks.shape
     assign = jnp.argmin(sq_dists(x, centroids), axis=1)
-    cent = centroids[assign]
-    codes = encode_pq(codebooks, x - cent)
-    csub = cent.reshape(x.shape[0], m, dsub)
-    recon = jnp.take_along_axis(
-        codebooks[None], codes[:, :, None, None], axis=2)[:, :, 0, :]
-    bias = 2.0 * jnp.sum(csub * recon, axis=(1, 2))
-    return assign, codes, bias.astype(jnp.float32)
+    codes, bias, _ = encode_residuals(codebooks, centroids,
+                                      x - centroids[assign], assign)
+    return assign, codes.astype(jnp.int32), bias.astype(jnp.float32)
 
 
 def make_mutable(state, config: StreamConfig

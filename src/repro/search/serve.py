@@ -570,6 +570,27 @@ def sharded_search_fn(sstate: ShardedEngineState, queries: jax.Array, k: int,
     return f(sstate, queries)
 
 
+def _build_record(spec: IndexSpec, payload, n: int) -> dict:
+    """What the blocked build did, for the metrics' ``build.*`` section:
+    the rows, the rows of one build block and the blocks of a pass (the
+    coarse k-means's where there is one, else the PQ codebooks'), and the
+    cells' fill."""
+    from .ivf import block_rows
+    rec = {"rows": n, "block_rows": None, "blocks": None, "nlist": None,
+           "max_cell": None}
+    tile = None
+    if spec.coarse is not None:
+        tile = spec.coarse.nlist
+        rec.update(nlist=spec.coarse.nlist,
+                   max_cell=int(payload.lists.shape[1]))
+    elif spec.code is not None:
+        tile = min(spec.code.centroids, n)
+    if tile is not None:
+        rec["block_rows"] = block_rows(n, tile)
+        rec["blocks"] = -(-n // rec["block_rows"])
+    return rec
+
+
 def _bucket(nq: int, floor: int, small: int = 0) -> int:
     """Smallest power-of-two >= nq, floored at ``floor`` — except batches of
     at most ``small``, which take their own power-of-two bucket so tiny
@@ -607,27 +628,31 @@ class SearchEngine:
         self._user_corpus = corpus if corpus is corpus_in else None
         n, dim = corpus.shape
         key = jax.random.key(config.seed)
-        if spec.reduce is not None:
-            mcfg = config.mpad
-            if mcfg is None and spec.reduce.kind == "qpad":
-                mcfg = MPADConfig(
-                    m=spec.reduce.m, b=80.0, alpha=25.0, iters=48,
-                    seed=config.seed)
-            sample = corpus
-            if config.fit_sample < n:
-                rows = jax.random.choice(
-                    key, n, (config.fit_sample,), replace=False)
-                sample = corpus[rows]
-            proj: Optional[Reducer] = fit_reducer(
-                spec.reduce.kind, key, sample, spec.reduce.m, mcfg)
-            reduced = reduce_vectors(proj, corpus)
-        else:
-            proj = None
-            reduced = corpus
-        payload = get_ops(config.index).build(key, reduced, spec)
+        with span("qpad.build"):
+            if spec.reduce is not None:
+                mcfg = config.mpad
+                if mcfg is None and spec.reduce.kind == "qpad":
+                    mcfg = MPADConfig(
+                        m=spec.reduce.m, b=80.0, alpha=25.0, iters=48,
+                        seed=config.seed)
+                with span("qpad.build.fit"):
+                    sample = corpus
+                    if config.fit_sample < n:
+                        rows = jax.random.choice(
+                            key, n, (config.fit_sample,), replace=False)
+                        sample = corpus[rows]
+                    proj: Optional[Reducer] = fit_reducer(
+                        spec.reduce.kind, key, sample, spec.reduce.m, mcfg)
+                with span("qpad.build.reduce"):
+                    reduced = reduce_vectors(proj, corpus)
+            else:
+                proj = None
+                reduced = corpus
+            payload = get_ops(config.index).build(key, reduced, spec)
         state = EngineState(corpus=corpus, proj=proj,
                             index=Index(config.index, payload))
         self._attach(config, state, proj)
+        self._build = _build_record(spec, payload, n)
 
     # --- lifecycle --------------------------------------------------------
 
@@ -637,6 +662,9 @@ class SearchEngine:
         programs, compile caches, counters. The shared tail of ``__init__``
         and the snapshot-restore constructors."""
         self._user_corpus = getattr(self, "_user_corpus", None)
+        self._build = None           # how __init__ built the index (the
+        #                              metrics' build.* section); None on
+        #                              engines restored from a snapshot
         self.config = config
         self.reducer = reducer
         self.state: Optional[EngineState] = state

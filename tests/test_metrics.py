@@ -83,6 +83,33 @@ def test_typed_surface_dotted_names():
     assert ro.engine.streaming is False
 
 
+@pytest.mark.parametrize("tile,block_rows,blocks", [(None, 600, 1),
+                                                     (4 * 12 * 64, 64, 10)])
+def test_build_section(monkeypatch, tile, block_rows, blocks):
+    """build.* says how the index was built: its rows, the rows of one
+    block of the coarse k-means and the blocks a pass takes, and the
+    cells' fill with the padded scan's width at the engine's nprobe."""
+    from repro.search import ivf
+    if tile is not None:
+        monkeypatch.setattr(ivf, "BUILD_TILE_BYTES", tile)
+    eng = build_engine(_data(), "ivf12x4>pq8x64>rr40")
+    flat = eng.metrics().flatten()
+    max_cell = int(eng.state.index.payload.lists.shape[1])
+    sizes = np.asarray((eng.state.index.payload.lists >= 0).sum(axis=1))
+    assert max_cell == sizes.max()
+    assert flat["build.rows"] == N
+    assert flat["build.block_rows"] == block_rows
+    assert flat["build.blocks"] == blocks
+    assert flat["build.nlist"] == 12
+    assert flat["build.max_cell"] == max_cell
+    assert flat["build.mean_cell"] == pytest.approx(N / 12)
+    assert flat["build.padded_width"] == 4 * max_cell
+    # a flat index has no blocked pass and no cells
+    ro = build_engine(_data(), "flat").metrics().flatten()
+    assert ro["build.rows"] == N and ro["build.block_rows"] is None
+    assert ro["build.padded_width"] is None
+
+
 def test_typed_surface_wal_policy_and_follower_sections(tmp_path):
     """Durable engines expose wal.* (fsyncs, floor), policy engines
     policy.* (drift + decision counters), followers replication.*."""

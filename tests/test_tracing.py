@@ -157,6 +157,38 @@ def test_compaction_fold_runs_on_its_own_host_line(tmp_path):
     assert begin[3] <= folded[2] and folded[3] <= install[3]
 
 
+@pytest.mark.parametrize("spec,phases", [
+    ("qpad8>ivf12x4>pq8x64>rr40", ["fit", "reduce", "coarse", "assign",
+                                   "codebooks", "encode", "layout"]),
+    ("ivf12x4", ["coarse", "assign", "layout"]),
+    ("pq8x64>rr40", ["codebooks", "encode"])])
+def test_build_spans_open_in_order(tmp_path, spec, phases):
+    """Set-up: qpad.build on the caller's line, holding one span a phase
+    of the index build in the order the build runs them."""
+    events, caller = _host_events(tmp_path, lambda: build_engine(
+        _data(), spec, fit_sample=256))
+    build = _one(events, "qpad.build")
+    assert build[0] == caller
+    assert _children(events, build) == [f"qpad.build.{p}" for p in phases]
+
+
+@pytest.mark.parametrize("spec,knobs", [
+    ("ivf12x4>pq8x64>rr40", {}),
+    ("ivf12x4>pq8x64>rr40", {"scan_cap": 128}),
+    ("ivf12x4", {})])
+def test_lowered_search_holds_the_probe_inside_the_scan(spec, knobs):
+    """The coarse probe (centroid distances and their top_k) is named
+    qpad.probe, nested in qpad.scan, so the scan's readers still count
+    it; the padded and the compact scan alike."""
+    from repro.search.serve import _SEARCH_STATICS, search_fn
+    eng = build_engine(_data(), spec)
+    text = jax.jit(search_fn, static_argnames=_SEARCH_STATICS).lower(
+        eng.state, _queries(), K, nprobe=4, rerank=40, **knobs
+    ).as_text(debug_info=True)
+    assert "qpad.scan/qpad.probe/dot_general" in text
+    assert "qpad.scan/qpad.probe/top_k" in text
+
+
 def test_profiling_changes_no_results_or_compiles(tmp_path):
     plain = build_engine(_data(), "ivf12x4>pq8x64>rr40")
     profiled = build_engine(_data(), "ivf12x4>pq8x64>rr40")
